@@ -100,7 +100,7 @@ def main():
         env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
         proc = subprocess.Popen(
             [sys.executable, "-m", "repro.launch.serve", "--arch", args.arch,
-             "--http", str(port), "--max-len",
+             "--reduce", "--http", str(port), "--max-len",
              str(args.prompt_len + args.max_new)],
             env=env, cwd=REPO)
     try:

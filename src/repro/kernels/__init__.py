@@ -3,9 +3,10 @@
 Importing this package registers the fused butterfly and block-sparse
 pixelfly kernels as the accelerator backends for their kinds — the core
 layer (``repro.core.factorized.Linear``) never imports kernel modules; it
-calls ``registry.ensure_kernels_registered()`` which imports us.  Blocks
-below the MXU-worthwhile threshold fall back to the jnp reference path via
-the ``supports`` predicate.
+calls ``registry.ensure_kernels_registered()`` which imports us.  Each
+backend's ``supports`` predicate says exactly which specs its kernel
+compiles for on the chip (lane-aligned blocks, b a multiple of 128); every
+other spec takes the jnp reference path, on every platform.
 
 The raw pallas_calls have no JVP rule, so each backend is wrapped in a
 custom VJP: kernel forward, reference-``spec.apply`` backward.  The two
@@ -16,11 +17,8 @@ instead of crashing in ``jax.grad``.
 import jax
 
 from repro.core.registry import register_kernel
-from repro.kernels.butterfly.ops import butterfly_linear
-from repro.kernels.pixelfly.ops import pixelfly_linear
-
-# below this block size the Pallas kernels lose to the jnp einsum path
-MIN_KERNEL_BLOCK = 8
+from repro.kernels.butterfly import ops as butterfly_ops
+from repro.kernels.pixelfly import ops as pixelfly_ops
 
 
 def _differentiable(kernel_fn):
@@ -42,7 +40,7 @@ def _differentiable(kernel_fn):
     return apply
 
 
-register_kernel("butterfly", _differentiable(butterfly_linear),
-                supports=lambda spec: spec.block_size >= MIN_KERNEL_BLOCK)
-register_kernel("pixelfly", _differentiable(pixelfly_linear),
-                supports=lambda spec: spec.block_size >= MIN_KERNEL_BLOCK)
+register_kernel("butterfly", _differentiable(butterfly_ops.butterfly_linear),
+                supports=butterfly_ops.supports)
+register_kernel("pixelfly", _differentiable(pixelfly_ops.pixelfly_linear),
+                supports=pixelfly_ops.supports)

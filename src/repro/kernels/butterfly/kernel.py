@@ -14,7 +14,10 @@ factor weights arrive packed as (L, nb, 2, b, b):
     w_packed[l, o, c] = W_l[j, r, c, t]   with  o = j*2s + r*s + t,  s = 2^l
 
 so output block ``o`` of factor ``l`` is x_block(o & ~s) @ w[o, 0] +
-x_block(o | s) @ w[o, 1].
+x_block(o | s) @ w[o, 1].  Blocks ``a`` and ``a | s`` (bit s of ``a`` clear)
+read and write only each other, so each factor step is a static loop over
+those pairs, four 2-D ``(TM, b) @ (b, b)`` MXU dots per pair, written back
+in place.
 """
 from __future__ import annotations
 
@@ -26,9 +29,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.utils import ilog2
-
-# jax renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+from repro.kernels.vmem import compiler_params
 
 
 def pack_factors(factors, num_blocks: int, block_size: int) -> jax.Array:
@@ -41,29 +42,50 @@ def pack_factors(factors, num_blocks: int, block_size: int) -> jax.Array:
     return jnp.stack(packed)
 
 
-def _fused_kernel(x_ref, w_ref, o_ref, scratch, *, num_factors: int, block_size: int):
+def fused_vmem_bytes(batch_tile: int, n: int, block_size: int,
+                     x_bytes: int, w_bytes: int) -> int:
+    """VMEM the fused kernel holds: the x and y tiles and one factor's
+    weight slab, each double-buffered by the pipeline, the f32 activation
+    scratch, and one pair's f32 products."""
+    tiles = 2 * 2 * batch_tile * n * x_bytes
+    weights = 2 * 2 * n * block_size * w_bytes
+    scratch = batch_tile * n * 4
+    pair = 4 * batch_tile * block_size * 4
+    return tiles + weights + scratch + pair
+
+
+def _fused_kernel(x_ref, w_ref, o_ref, scratch, *, num_factors: int,
+                  block_size: int):
     l = pl.program_id(1)
-    tm, n = x_ref.shape
-    nb = n // block_size
+    nb = x_ref.shape[1] // block_size
+    b = block_size
 
     @pl.when(l == 0)
     def _load():
         scratch[...] = x_ref[...].astype(scratch.dtype)
 
-    # One static branch per factor: stride is a Python constant inside each,
-    # so the strided block view is a static reshape (MXU-friendly dot per pair).
+    # One static branch per factor: the stride is a Python constant inside
+    # each, so every block slice is static and lane-aligned.
     for lf in range(num_factors):
         @pl.when(l == lf)
         def _apply(lf=lf):
             s = 1 << lf
-            j = nb // (2 * s)
-            cur = scratch[...].reshape(tm, j, 2, s, block_size)        # (m,j,c,t,i)
-            w = w_ref[0].reshape(j, 2, s, 2, block_size, block_size)   # (j,r,t,c,i,o)
-            y = jnp.einsum(
-                "mjcti,jrtcio->mjrto", cur, w,
-                preferred_element_type=jnp.float32,
-            )
-            scratch[...] = y.reshape(tm, n).astype(scratch.dtype)
+            for a in range(nb):
+                if a & s:
+                    continue
+                c = a | s
+                xa = scratch[:, a * b:(a + 1) * b].astype(w_ref.dtype)
+                xc = scratch[:, c * b:(c + 1) * b].astype(w_ref.dtype)
+
+                def mix(o):
+                    return (jnp.dot(xa, w_ref[0, o, 0],
+                                    preferred_element_type=jnp.float32)
+                            + jnp.dot(xc, w_ref[0, o, 1],
+                                      preferred_element_type=jnp.float32))
+
+                ya, yc = mix(a), mix(c)
+                scratch[:, a * b:(a + 1) * b] = ya
+                scratch[:, c * b:(c + 1) * b] = yc
 
     @pl.when(l == num_factors - 1)
     def _store():
@@ -95,6 +117,8 @@ def fused_butterfly_apply(
     kernel = functools.partial(
         _fused_kernel, num_factors=num_factors, block_size=block_size
     )
+    need = fused_vmem_bytes(batch_tile, n, block_size, x.dtype.itemsize,
+                            w_packed.dtype.itemsize)
     return pl.pallas_call(
         kernel,
         grid=grid,
@@ -107,19 +131,27 @@ def fused_butterfly_apply(
         out_specs=pl.BlockSpec((batch_tile, n), lambda i, l: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         scratch_shapes=[pltpu.VMEM((batch_tile, n), jnp.float32)],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-        ),
+        compiler_params=compiler_params(("parallel", "arbitrary"), need),
         interpret=interpret,
+        name="butterfly_fused",
     )(x, w_packed)
 
 
-def _single_factor_kernel(x_ref, w_ref, o_ref):
-    """Unfused single-factor kernel (one grid step mixes one block pair)."""
-    x = x_ref[:, 0, :, 0, :]  # (TM, c=2, b)
-    w = w_ref[0, :, :, 0]     # (r, c, i, o)
-    y = jnp.einsum("mci,rcio->mro", x, w, preferred_element_type=jnp.float32)
-    o_ref[:, 0, :, 0, :] = y.astype(o_ref.dtype)
+def _single_factor_kernel(x_ref, w_ref, o_ref, acc):
+    """Unfused single-factor kernel: output block o accumulates its two
+    inputs, one per step of the innermost grid axis (input slot c)."""
+    c = pl.program_id(2)
+
+    @pl.when(c == 0)
+    def _init():
+        acc[...] = jnp.zeros_like(acc)
+
+    acc[...] += jnp.dot(x_ref[...].astype(w_ref.dtype), w_ref[0, 0, 0, 0],
+                        preferred_element_type=jnp.float32)
+
+    @pl.when(c == 1)
+    def _store():
+        o_ref[...] = acc[...].astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -134,35 +166,39 @@ def butterfly_factor_apply(
     batch_tile: int = 128,
     interpret: bool = False,
 ) -> jax.Array:
-    """Apply ONE butterfly factor.  x: (M, N); w: (J, 2, 2, S, b, b)."""
+    """Apply ONE butterfly factor.  x: (M, N); w: (J, 2, 2, S, b, b).
+
+    Output block o = j*2s + r*s + t reads input blocks j*2s + c*s + t for
+    c in {0, 1} with weight w[j, r, c, t]; both block indices are computed
+    in the index_maps, so x and y keep their plain (M, N) layout."""
     m, n = x.shape
     nb = n // block_size
-    j, s = nb // (2 * stride), stride
+    s = stride
+    j = nb // (2 * s)
     assert w.shape == (j, 2, 2, s, block_size, block_size)
     assert m % batch_tile == 0
 
-    # view x as (M, J, 2, S, b) without data movement; grid over (m, j, t)
-    xv = x.reshape(m, j, 2, s, block_size)
-    grid = (m // batch_tile, j, s)
-    out = pl.pallas_call(
+    def x_block(i, o, c):
+        return i, (o // (2 * s)) * (2 * s) + c * s + o % s
+
+    def w_block(i, o, c):
+        return o // (2 * s), (o // s) % 2, c, o % s, 0, 0
+
+    b = block_size
+    need = 2 * (2 * batch_tile * b * x.dtype.itemsize
+                + b * b * w.dtype.itemsize) + batch_tile * b * 4
+    return pl.pallas_call(
         _single_factor_kernel,
-        grid=grid,
+        grid=(m // batch_tile, nb, 2),
         in_specs=[
-            pl.BlockSpec(
-                (batch_tile, 1, 2, 1, block_size), lambda i, jj, t: (i, jj, 0, t, 0)
-            ),
-            pl.BlockSpec(
-                (1, 2, 2, 1, block_size, block_size),
-                lambda i, jj, t: (jj, 0, 0, t, 0, 0),
-            ),
+            pl.BlockSpec((batch_tile, b), x_block),
+            pl.BlockSpec((1, 1, 1, 1, b, b), w_block),
         ],
-        out_specs=pl.BlockSpec(
-            (batch_tile, 1, 2, 1, block_size), lambda i, jj, t: (i, jj, 0, t, 0)
-        ),
-        out_shape=jax.ShapeDtypeStruct((m, j, 2, s, block_size), x.dtype),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel"),
-        ),
+        out_specs=pl.BlockSpec((batch_tile, b), lambda i, o, c: (i, o)),
+        out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
+        scratch_shapes=[pltpu.VMEM((batch_tile, b), jnp.float32)],
+        compiler_params=compiler_params(
+            ("parallel", "parallel", "arbitrary"), need),
         interpret=interpret,
-    )(xv, w)
-    return out.reshape(m, n)
+        name="butterfly_factor",
+    )(x, w)

@@ -13,7 +13,9 @@ import jax.numpy as jnp
 
 from repro.core.butterfly import ButterflySpec
 from repro.core.utils import bit_reversal_permutation
-from repro.kernels.butterfly.kernel import fused_butterfly_apply, pack_factors
+from repro.kernels.butterfly.kernel import (fused_butterfly_apply,
+                                           fused_vmem_bytes, pack_factors)
+from repro.kernels.vmem import LANES, TILE_BUDGET, VMEM_CAPACITY
 
 import numpy as np
 
@@ -22,18 +24,24 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def _pick_batch_tile(m: int, n: int, block_size: int,
-                     dtype_bytes: int = 4) -> int:
-    """Pick TM so the resident working set fits ~12MB VMEM: the activation
-    tiles (input + f32 scratch + output, each (TM, N)) PLUS the per-factor
-    packed weight slab ((nb, 2, b, b) = 2*N*b elements) that the grid
-    pipeline streams in alongside them."""
-    budget = 12 * 2**20
-    factor_bytes = 2 * n * block_size * dtype_bytes
-    for tm in (512, 256, 128, 64, 32, 16, 8):
-        if 3 * tm * n * dtype_bytes + factor_bytes <= budget:
-            return tm
-    return 8
+def _pick_batch_tile(m: int, n: int, block_size: int, x_bytes: int,
+                     w_bytes: int) -> int:
+    """Largest TM whose double-buffered tiles, weight slab and f32 scratch
+    fit the VMEM tile budget (else the smallest, for which the kernel
+    raises its VMEM limit); M itself when one tile holds every row."""
+    for tm in (512, 256, 128, 64, 32, 16):
+        if fused_vmem_bytes(tm, n, block_size, x_bytes, w_bytes) \
+                <= TILE_BUDGET:
+            break
+    return m if m <= tm else tm
+
+
+def supports(spec: ButterflySpec) -> bool:
+    """What the fused kernel compiles for on the chip: lane-aligned blocks
+    (b a multiple of 128) whose smallest tile fits physical VMEM."""
+    return (spec.block_size % LANES == 0
+            and fused_vmem_bytes(16, spec.n_padded, spec.block_size, 4, 4)
+            <= VMEM_CAPACITY)
 
 
 def fused_apply(
@@ -56,10 +64,10 @@ def fused_apply(
     lead = x.shape[:-1]
     m = int(np.prod(lead)) if lead else 1
     xf = x.reshape(m, n)
-    tm = batch_tile or _pick_batch_tile(m, n, block_size)
     # decode fast path: inputs narrower than a tile (M = num_slots, e.g. 4)
     # take a single exact tile instead of padding up to 8 — no wasted rows
-    tm = min(tm, max(1, m))
+    tm = min(batch_tile, m) if batch_tile else _pick_batch_tile(
+        m, n, block_size, x.dtype.itemsize, w_packed.dtype.itemsize)
     pad = (-m) % tm
     if pad:
         xf = jnp.pad(xf, ((0, pad), (0, 0)))

@@ -6,7 +6,8 @@ structure: output block-row ``o`` reads input block-cols ``o`` and
 computed inside the BlockSpec ``index_map`` from the grid position, so the
 kernel streams exactly the log2(nb)+1 relevant (TM, b) input tiles per output
 tile and accumulates in the revolving output block (standard Pallas K-loop
-accumulation with the contraction axis innermost).
+accumulation with the contraction axis innermost).  x and y keep their
+plain (M, N) layout: a (TM, b) block of column-block ``o`` is a 2-D tile.
 
 This is the TPU replacement for the paper's GPU/Triton block alignment: the
 support blocks are already MXU-shaped, so "alignment" is free and the
@@ -22,9 +23,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.utils import ilog2
-
-# jax renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+from repro.kernels.vmem import compiler_params
 
 
 def _col_index(o, j):
@@ -43,13 +42,13 @@ def _bsmm_kernel(x_ref, w_ref, o_ref, acc):
     def _init():
         acc[...] = jnp.zeros_like(acc)
 
-    x = x_ref[...]          # (TM, 1, b) tile of the needed input block-col
-    w = w_ref[0, 0]         # (b, b): maps input col block -> output row block
-    acc[...] += jnp.dot(x[:, 0, :], w, preferred_element_type=jnp.float32)
+    # (TM, b) tile of the needed input block-col @ (b, b) support block
+    acc[...] += jnp.dot(x_ref[...].astype(w_ref.dtype), w_ref[0, 0],
+                        preferred_element_type=jnp.float32)
 
     @pl.when(j == k - 1)
     def _store():
-        o_ref[...] = acc[...][:, None, :].astype(o_ref.dtype)
+        o_ref[...] = acc[...].astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -70,32 +69,25 @@ def pixelfly_bsmm(
     """
     m, n = x.shape
     nb, k = w_blocks.shape[0], w_blocks.shape[1]
-    assert nb * block_size == n
+    b = block_size
+    assert nb * b == n
     assert k == 1 + ilog2(nb)
     assert m % batch_tile == 0
 
-    xv = x.reshape(m, nb, block_size)
-    grid = (m // batch_tile, nb, k)
-    out = pl.pallas_call(
+    need = 2 * (2 * batch_tile * b * x.dtype.itemsize
+                + b * b * w_blocks.dtype.itemsize) + batch_tile * b * 4
+    return pl.pallas_call(
         _bsmm_kernel,
-        grid=grid,
+        grid=(m // batch_tile, nb, k),
         in_specs=[
-            pl.BlockSpec(
-                (batch_tile, 1, block_size),
-                lambda i, o, j: (i, _col_index(o, j), 0),
-            ),
-            pl.BlockSpec(
-                (1, 1, block_size, block_size), lambda i, o, j: (o, j, 0, 0)
-            ),
+            pl.BlockSpec((batch_tile, b), lambda i, o, j: (i, _col_index(o, j))),
+            pl.BlockSpec((1, 1, b, b), lambda i, o, j: (o, j, 0, 0)),
         ],
-        out_specs=pl.BlockSpec(
-            (batch_tile, 1, block_size), lambda i, o, j: (i, o, 0)
-        ),
-        out_shape=jax.ShapeDtypeStruct((m, nb, block_size), x.dtype),
-        scratch_shapes=[pltpu.VMEM((batch_tile, block_size), jnp.float32)],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
+        out_specs=pl.BlockSpec((batch_tile, b), lambda i, o, j: (i, o)),
+        out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
+        scratch_shapes=[pltpu.VMEM((batch_tile, b), jnp.float32)],
+        compiler_params=compiler_params(
+            ("parallel", "parallel", "arbitrary"), need),
         interpret=interpret,
-    )(xv, w_blocks)
-    return out.reshape(m, n)
+        name="pixelfly_bsmm",
+    )(x, w_blocks)

@@ -7,10 +7,17 @@ import numpy as np
 
 from repro.core.pixelfly import PixelflySpec
 from repro.kernels.pixelfly.kernel import pixelfly_bsmm
+from repro.kernels.vmem import LANES
 
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
+
+
+def supports(spec: PixelflySpec) -> bool:
+    """What the kernel compiles for on the chip: lane-aligned (TM, b)
+    tiles, i.e. b a multiple of 128."""
+    return spec.block_size % LANES == 0
 
 
 def bsmm(
@@ -28,7 +35,8 @@ def bsmm(
     lead = x.shape[:-1]
     m = int(np.prod(lead)) if lead else 1
     xf = x.reshape(m, n)
-    tm = min(batch_tile, max(8, m))
+    # one exact tile when every row fits (decode: M = num_slots)
+    tm = min(batch_tile, m)
     pad = (-m) % tm
     if pad:
         xf = jnp.pad(xf, ((0, pad), (0, 0)))

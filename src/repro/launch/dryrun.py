@@ -43,6 +43,9 @@ from repro.train.train_step import (
 OUT_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                        "experiments", "dryrun")
 BF16_PARAMS = False  # flipped by --bf16-params (see EXPERIMENTS.md sec Perf)
+# the chip the dry run prices its rooflines against: TPU v5e, by the
+# device_kind JAX reports for it
+TARGET_DEVICE_KIND = "TPU v5 lite"
 
 
 def pick_microbatch(cfg: ModelConfig, shape: ShapeConfig) -> int:
@@ -176,7 +179,8 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     shape = SHAPES[shape_name]
     mesh_name = "2x16x16" if multi_pod else "16x16"
     cell_arch = arch + (f"+{fact}" if fact and fact != "dense" else "")
-    rec = {"arch": cell_arch, "shape": shape_name, "mesh": mesh_name}
+    rec = {"arch": cell_arch, "shape": shape_name, "mesh": mesh_name,
+           "target": TARGET_DEVICE_KIND}
     if not shape_applicable(cfg, shape):
         rec["status"] = "skipped"
         rec["reason"] = "long_500k needs sub-quadratic mixing (DESIGN.md s5)"
@@ -197,7 +201,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
                 t_lower = time.time() - t0
                 compiled = lowered.compile()
                 t_compile = time.time() - t0 - t_lower
-        roof = analyze_compiled(compiled)
+        roof = analyze_compiled(compiled, TARGET_DEVICE_KIND)
         mem = memory_summary(compiled)
         mf = model_flops(cfg, shape.global_batch, shape.seq_len, shape.kind)
         n_chips = mesh.size

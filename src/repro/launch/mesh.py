@@ -3,16 +3,12 @@ module never touches jax device state)."""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
-def make_mesh_compat(shape, axes):
-    """jax.make_mesh grew axis_types (jax.sharding.AxisType) after 0.4.x;
-    pass it when available, fall back to the plain call otherwise."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+def make_mesh(shape, axes):
+    """A device mesh whose axes GSPMD partitions automatically."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -23,7 +19,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def dp_axes(mesh) -> tuple[str, ...]:
@@ -33,7 +29,7 @@ def dp_axes(mesh) -> tuple[str, ...]:
 
 def make_debug_mesh(data: int = 2, model: int = 2):
     """Small mesh for in-process multi-device tests (host platform devices)."""
-    return make_mesh_compat((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def make_serving_mesh(dp: int, tp: int):
@@ -46,15 +42,10 @@ def make_serving_mesh(dp: int, tp: int):
             f"mesh dp={dp} x tp={tp} needs {need} devices but only {have} "
             "are visible; on CPU set XLA_FLAGS="
             f"--xla_force_host_platform_device_count={need}")
-    return make_mesh_compat((dp, tp), ("data", "model"))
+    return make_mesh((dp, tp), ("data", "model"))
 
 
 def make_abstract_mesh(shape, axes):
     """Device-free mesh stand-in (``.shape``/``.axis_names`` only) for
-    spec-level planning, e.g. per-device serving budgets on a login host.
-    jax changed the AbstractMesh constructor across versions; support both."""
-    am = jax.sharding.AbstractMesh
-    try:
-        return am(tuple(shape), tuple(axes))          # >= 0.5 style
-    except TypeError:
-        return am(tuple(zip(axes, shape)))            # 0.4.x shape_tuple
+    spec-level planning, e.g. per-device serving budgets on a login host."""
+    return jax.sharding.AbstractMesh(tuple(shape), tuple(axes))

@@ -1,6 +1,8 @@
 """Serving CLI: a thin shell over the continuous-batching ``Engine``.
 
-CPU container: runs reduced configs for real.  Requests are admitted into
+Builds the architecture at its published widths, with random weights made
+on the device in the compute dtype; ``--reduce`` swaps in the 128-wide
+smoke preset that a CPU can run.  Requests are admitted into
 decode slots over a PAGED KV cache by default (``--page-size`` blocks; the
 scheduler admits against free pages, so short requests stop paying for
 ``max_len`` stripes — ``--fixed-slots`` falls back to the dense SlotCache),
@@ -66,11 +68,11 @@ import numpy as np
 
 from repro.configs import get_config, reduced
 from repro.core.policy import FactorizationPolicy, uniform_policy
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_serving_mesh
-from repro.models import init_params
 from repro.serving import (AsyncEngine, Engine, LocalExecutor, Request,
-                           RequestOutput, SamplingParams, make_requests,
-                           percentile, resolve_engine_spec)
+                           RequestOutput, SamplingParams, init_serving_params,
+                           make_requests, percentile, resolve_engine_spec)
 from repro.serving.budget import plan_engine_report
 
 logging.basicConfig(level=logging.INFO, format="%(asctime)s %(message)s")
@@ -578,10 +580,12 @@ def run_batch(args, engine: Engine, cfg) -> None:
              list(outputs[0].tokens)[:12])
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-4b")
-    ap.add_argument("--reduce", action="store_true", default=True)
+    ap.add_argument("--reduce", action="store_true",
+                    help="serve the reduced smoke config (CPU-sized) "
+                         "instead of the published widths")
     ap.add_argument("--batch", type=int, default=4,
                     help="number of requests to serve (batch mode)")
     ap.add_argument("--prompt-len", type=int, default=16)
@@ -616,7 +620,7 @@ def main():
     ap.add_argument("--swap", action="store_true",
                     help="undo preemptions by restoring the victim's KV "
                          "blocks from a host copy instead of recomputing "
-                         "them (pinned host memory when available)")
+                         "them (pinned host memory)")
     ap.add_argument("--chunk-size", type=int, default=0,
                     help="chunked prefill: per-step prefill token budget "
                          "composed WITH decode into one mixed dispatch, so "
@@ -660,8 +664,14 @@ def main():
     ap.add_argument("--fact-block", type=int, default=32)
     ap.add_argument("--policy-json", default="",
                     help="path to a FactorizationPolicy JSON (wins over --fact)")
-    args = ap.parse_args()
+    return ap
 
+
+def setup(args):
+    """(engine, cfg) for parsed CLI ``args``: the config at its published
+    widths (or the smoke preset under ``--reduce``) with the chosen
+    policy, the --dp/--tp mesh, weights created in place, and the
+    engine over them."""
     cfg = get_config(args.arch)
     if args.reduce:
         cfg = reduced(cfg)
@@ -672,7 +682,6 @@ def main():
         raise SystemExit(f"{cfg.name} takes frontend embeddings; use "
                          "examples/serve_decode.py for the stub flow")
 
-    params = init_params(cfg, jax.random.PRNGKey(0))
     max_len = args.max_len or (args.prompt_len + args.max_new)
     mesh = None
     if args.dp * args.tp > 1:
@@ -682,6 +691,7 @@ def main():
             raise SystemExit(str(e))
         log.info("mesh: dp=%d x tp=%d over %d devices",
                  args.dp, args.tp, args.dp * args.tp)
+    params = init_serving_params(cfg, jax.random.PRNGKey(0), mesh)
     engine = build_engine(args, cfg, params, max_len, mesh)
     log.info("engine: %d slots, %s, cache %.2f MB%s",
              engine.num_slots,
@@ -690,7 +700,13 @@ def main():
               else f"token budget {engine.scheduler.token_budget}"),
              engine.cache.nbytes() / 1e6,
              " (sharded over the mesh)" if mesh is not None else "")
+    return engine, cfg
 
+
+def main():
+    args = build_parser().parse_args()
+    use_compile_cache()
+    engine, cfg = setup(args)
     if args.http is not None:
         try:
             asyncio.run(http_serve(engine, args.host, args.http))

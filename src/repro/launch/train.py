@@ -15,10 +15,10 @@ multi-device path.)
 from __future__ import annotations
 
 import argparse
-import dataclasses
 import json
 import logging
 import os
+import tempfile
 import time
 
 import jax
@@ -29,6 +29,7 @@ from repro.checkpoint.manager import CheckpointManager
 from repro.configs import get_config, reduced
 from repro.core.policy import FactorizationPolicy, uniform_policy
 from repro.data.synthetic import embeddings_batch, lm_batch
+from repro.launch.compile_cache import use_compile_cache
 from repro.runtime.fault_tolerance import (
     PreemptionHandler,
     StragglerWatchdog,
@@ -62,47 +63,26 @@ def resolve_policy(args) -> FactorizationPolicy | None:
     return None
 
 
-def main():
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="butterfly-lm-100m")
-    ap.add_argument("--reduce", action="store_true",
-                    help="use the reduced smoke config (CPU-trainable)")
-    ap.add_argument("--steps", type=int, default=50)
-    ap.add_argument("--batch", type=int, default=8)
-    ap.add_argument("--seq", type=int, default=128)
-    ap.add_argument("--lr", type=float, default=1e-3)
-    ap.add_argument("--microbatch", type=int, default=0)
-    ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
-    ap.add_argument("--ckpt-every", type=int, default=25)
-    ap.add_argument("--resume", action="store_true")
-    ap.add_argument("--fact", default="",
-                    help="uniform factorization kind at the classic sites "
-                         "(butterfly|pixelfly|...)")
-    ap.add_argument("--fact-block", type=int, default=32)
-    ap.add_argument("--policy-json", default="",
-                    help="path to a FactorizationPolicy JSON (per-site rules;"
-                         " overrides --fact)")
-    args = ap.parse_args()
-
-    cfg = get_config(args.arch)
-    if args.reduce:
-        cfg = reduced(cfg)
-    policy = resolve_policy(args)
-    if policy is not None:
-        cfg = cfg.with_fact(policy)
-    tc = TrainConfig(lr=args.lr, microbatch=args.microbatch,
-                     schedule="warmup_cosine", warmup=max(args.steps // 10, 5),
-                     total_steps=args.steps)
+def train(cfg, *, steps: int, batch: int, seq: int, lr: float = 1e-3,
+          microbatch: int = 0, ckpt_dir: str, ckpt_every: int = 25,
+          resume: bool = False, max_failures: int = 3) -> list[float]:
+    """Train ``cfg`` for ``steps`` steps on synthetic data; returns the
+    per-step losses.  ``max_failures`` bounds how many failed steps are
+    restored from the last checkpoint and replayed before the error is
+    raised; 0 raises on the first."""
+    tc = TrainConfig(lr=lr, microbatch=microbatch,
+                     schedule="warmup_cosine", warmup=max(steps // 10, 5),
+                     total_steps=steps)
 
     state = init_train_state(cfg, tc, jax.random.PRNGKey(0))
     step_fn = jax.jit(make_train_step(cfg, tc))
-    batch_fn = make_batch_fn(cfg, args.batch, args.seq)
-    mgr = CheckpointManager(os.path.join(args.ckpt_dir, cfg.name), keep=3)
+    batch_fn = make_batch_fn(cfg, batch, seq)
+    mgr = CheckpointManager(os.path.join(ckpt_dir, cfg.name), keep=3)
     watchdog = StragglerWatchdog()
     preemption = PreemptionHandler().install()
 
     start = 0
-    if args.resume and mgr.latest_step() is not None:
+    if resume and mgr.latest_step() is not None:
         start, state = mgr.restore(state, policy=cfg.fact)
         log.info("resumed from step %d", start)
 
@@ -128,11 +108,11 @@ def main():
         return mgr.restore(state, policy=cfg.fact)
 
     final_step, state = run_fault_tolerant(
-        one_step, state, start, args.steps,
+        one_step, state, start, steps,
         save_fn=lambda s, st: mgr.save(s, st, blocking=False,
                                        policy=cfg.fact),
         restore_fn=restore_or_restart,
-        checkpoint_every=args.ckpt_every,
+        checkpoint_every=ckpt_every, max_failures=max_failures,
         watchdog=watchdog, preemption=preemption)
     mgr.wait()
     dt = time.time() - t0
@@ -143,6 +123,42 @@ def main():
     log.info("step-time stats: %s", watchdog.stats())
     mgr.save(final_step, state, policy=cfg.fact)
     preemption.uninstall()
+    return losses
+
+
+def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--arch", default="butterfly-lm-100m")
+    ap.add_argument("--reduce", action="store_true",
+                    help="use the reduced smoke config (CPU-trainable)")
+    ap.add_argument("--steps", type=int, default=50)
+    ap.add_argument("--batch", type=int, default=8)
+    ap.add_argument("--seq", type=int, default=128)
+    ap.add_argument("--lr", type=float, default=1e-3)
+    ap.add_argument("--microbatch", type=int, default=0)
+    ap.add_argument("--ckpt-dir",
+                    default=os.path.join(tempfile.gettempdir(), "repro_ckpt"))
+    ap.add_argument("--ckpt-every", type=int, default=25)
+    ap.add_argument("--resume", action="store_true")
+    ap.add_argument("--fact", default="",
+                    help="uniform factorization kind at the classic sites "
+                         "(butterfly|pixelfly|...)")
+    ap.add_argument("--fact-block", type=int, default=32)
+    ap.add_argument("--policy-json", default="",
+                    help="path to a FactorizationPolicy JSON (per-site rules;"
+                         " overrides --fact)")
+    args = ap.parse_args()
+
+    cfg = get_config(args.arch)
+    if args.reduce:
+        cfg = reduced(cfg)
+    policy = resolve_policy(args)
+    if policy is not None:
+        cfg = cfg.with_fact(policy)
+    use_compile_cache()
+    train(cfg, steps=args.steps, batch=args.batch, seq=args.seq, lr=args.lr,
+          microbatch=args.microbatch, ckpt_dir=args.ckpt_dir,
+          ckpt_every=args.ckpt_every, resume=args.resume)
 
 
 if __name__ == "__main__":

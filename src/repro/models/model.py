@@ -134,7 +134,9 @@ def _slot_decode(p: dict, cfg: ModelConfig, mixer: str, ffn: str,
 
 def cast_params(params, dtype):
     """Cast floating-point params to the compute dtype (bf16 matmuls on TPU);
-    norm scales stay f32 inside rms_norm, which upcasts internally."""
+    norm scales stay f32 inside rms_norm, which upcasts internally.  Leaves
+    already in ``dtype`` pass through untouched, so the call is free for
+    serving weights, which are held in the compute dtype from load."""
     def cast(x):
         return x.astype(dtype) if jnp.issubdtype(x.dtype, jnp.floating) else x
     return jax.tree.map(cast, params)

@@ -1,9 +1,9 @@
 """Roofline analysis from compiled XLA artifacts (no hardware needed).
 
-Terms (per device, seconds) for TPU v5e:
-    compute    = HLO_FLOPs / peak_FLOPs            (197 bf16 TFLOP/s)
-    memory     = HLO_bytes_accessed / HBM_bw       (819 GB/s)
-    collective = collective_operand_bytes / ICI_bw (~50 GB/s/link)
+Terms (per device, seconds), against the peaks of the target device:
+    compute    = HLO_FLOPs / peak_FLOPs
+    memory     = HLO_bytes_accessed / HBM_bw
+    collective = collective_operand_bytes / ICI_bw (per link)
 
 ``cost_analysis()`` reports per-device FLOPs/bytes for SPMD executables
 (verified empirically — a (64,128)x(128,256) matmul over 8 devices reports
@@ -18,11 +18,37 @@ import dataclasses
 import re
 from typing import Any
 
-# ------------------------- TPU v5e constants (per chip) -------------------
-PEAK_FLOPS = 197e12       # bf16 MXU
-VPU_FLOPS = 4e12          # vector unit (elementwise) — 8x128x4 ALUs @ .94GHz
-HBM_BW = 819e9            # bytes/s
-ICI_BW = 50e9             # bytes/s/link
+
+
+@dataclasses.dataclass(frozen=True)
+class DevicePeaks:
+    """Per-chip peak rates of one device kind."""
+
+    flops: float      # bf16 MXU FLOP/s
+    vpu_flops: float  # vector-unit (elementwise) FLOP/s
+    hbm_bw: float     # HBM bytes/s
+    ici_bw: float     # chip-to-chip bytes/s per link
+
+
+# Keyed by ``jax.Device.device_kind``.  TPU v5e (Google Cloud documentation,
+# "TPU v5e"): 197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s of ICI over four
+# links (50 GB/s each).  The vector-unit rate is not published; it is
+# derived from the VPU's 8x128x4 ALUs at 0.94 GHz.
+PEAKS = {
+    "TPU v5 lite": DevicePeaks(flops=197e12, vpu_flops=4e12, hbm_bw=819e9,
+                               ici_bw=50e9),
+}
+
+
+def peaks_for(device_kind: str) -> DevicePeaks:
+    """The peak table's entry for ``device_kind``; an unknown kind raises
+    rather than borrowing another chip's numbers."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no peak rates for device kind {device_kind!r}; "
+                       f"known: {sorted(PEAKS)}") from None
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -113,6 +139,7 @@ class Roofline:
     through fused matmuls, the latter models params+opt read/write and
     remat-stash traffic (argument + output + 2*temp)."""
 
+    peaks: DevicePeaks
     dot_flops: float
     ew_flops: float
     dot_bytes: float
@@ -135,15 +162,16 @@ class Roofline:
     def compute_s(self) -> float:
         # MXU for dots, VPU for elementwise — SSM/recurrent archs are
         # elementwise-heavy and would look free at MXU speed
-        return self.dot_flops / PEAK_FLOPS + self.ew_flops / VPU_FLOPS
+        return (self.dot_flops / self.peaks.flops
+                + self.ew_flops / self.peaks.vpu_flops)
 
     @property
     def memory_s(self) -> float:
-        return self.bytes_per_device / HBM_BW
+        return self.bytes_per_device / self.peaks.hbm_bw
 
     @property
     def collective_s(self) -> float:
-        return self.collective_bytes_per_device / ICI_BW
+        return self.collective_bytes_per_device / self.peaks.ici_bw
 
     @property
     def dominant(self) -> str:
@@ -183,11 +211,11 @@ class Roofline:
         }
 
 
-def analyze_compiled(compiled) -> Roofline:
+def analyze_compiled(compiled, device_kind: str) -> Roofline:
+    """Roofline of a compiled program against ``device_kind``'s peaks."""
     from repro.roofline.hlo_cost import hlo_cost
+    peaks = peaks_for(device_kind)
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # older jax: one dict per device
-        ca = ca[0] if ca else {}
     hlo = compiled.as_text()
     cost = hlo_cost(hlo)
     mem = memory_summary(compiled)
@@ -195,6 +223,7 @@ def analyze_compiled(compiled) -> Roofline:
                     + mem.get("output_size_in_bytes", 0.0)
                     + 2.0 * mem.get("temp_size_in_bytes", 0.0))
     return Roofline(
+        peaks=peaks,
         dot_flops=cost.dot_flops,
         ew_flops=cost.ew_flops,
         dot_bytes=cost.dot_bytes,
@@ -208,10 +237,7 @@ def analyze_compiled(compiled) -> Roofline:
 
 
 def memory_summary(compiled) -> dict[str, float]:
-    try:
-        ma = compiled.memory_analysis()
-    except Exception:
-        return {}
+    ma = compiled.memory_analysis()
     fields = ("argument_size_in_bytes", "output_size_in_bytes",
               "temp_size_in_bytes", "alias_size_in_bytes",
               "generated_code_size_in_bytes")

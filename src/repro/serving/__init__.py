@@ -38,7 +38,8 @@ from repro.serving.request import (
     make_requests,
     percentile,
 )
-from repro.serving.runner import ExecuteInput, ExecuteOutput, ModelRunner
+from repro.serving.runner import (ExecuteInput, ExecuteOutput, ModelRunner,
+                                  init_serving_params)
 from repro.serving.scheduler import Scheduler
 from repro.serving.utils import EngineStats
 
@@ -71,6 +72,7 @@ __all__ = [
     "SwapState",
     "TokenDelta",
     "cache_bytes_per_token",
+    "init_serving_params",
     "make_requests",
     "param_bytes",
     "percentile",

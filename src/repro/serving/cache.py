@@ -94,16 +94,12 @@ class PoolExhausted(MemoryError):
         self.shortfall = int(requested) - int(free)
 
 
-def host_copy(x):
-    """Device -> host copy for swap-out: pinned host memory when the
-    backend supports the memory kind (keeps the eventual restore a cheap
-    DMA), plain numpy otherwise (CPU backend, older runtimes)."""
-    try:
-        sharding = jax.sharding.SingleDeviceSharding(
-            jax.devices()[0], memory_kind="pinned_host")
-        return jax.block_until_ready(jax.device_put(x, sharding))
-    except Exception:
-        return np.asarray(x)
+def host_copy(x: jax.Array) -> jax.Array:
+    """Device -> pinned host memory for swap-out, keeping ``x``'s sharding
+    (each device's shard lands in its host's pinned memory, so the restore
+    is a DMA straight back into place)."""
+    return jax.block_until_ready(jax.device_put(
+        x, x.sharding.with_memory_kind("pinned_host")))
 
 
 def _check_slots(slots: TypingSequence[int], num_slots: int) -> None:
@@ -340,7 +336,7 @@ class SwapState:
     num_pages: int
 
     def nbytes(self) -> int:
-        return sum(np.asarray(x).nbytes for x in jax.tree.leaves(self.blocks))
+        return sum(x.nbytes for x in jax.tree.leaves(self.blocks))
 
 
 class PagedSlotCache:
@@ -385,7 +381,13 @@ class PagedSlotCache:
         self.data = init_paged_caches(cfg, num_slots, num_pages + 1, page_size)
         # blank single-slot template for the slot-indexed (recurrent) leaves
         self._blank = init_caches(cfg, 1, 1)
-        if shardings is not None:
+        if shardings is None:
+            # committed from the start: a swap-in restores committed leaves
+            # (they come back from pinned host memory to a named device), so
+            # an uncommitted pool would reach the decode step with a new
+            # compile key after the first restore
+            self.data = jax.device_put(self.data, jax.devices()[0])
+        else:
             self.data = jax.device_put(self.data, shardings)
             self._blank = jax.device_put(self._blank, jax.tree.map(
                 lambda s: jax.sharding.NamedSharding(
@@ -609,7 +611,7 @@ class PagedSlotCache:
     # ------------------------------------------------------------- swap --
     def swap_out(self, slot: int) -> "SwapState":
         """Copy ``slot``'s mapped blocks (attention K/V) and its recurrent
-        row to host memory (pinned when available) so a preemption can be
+        row to pinned host memory so a preemption can be
         undone by restore instead of recompute.  Read-only: the caller
         still owns the device pages and releases them via ``evict``.
         Shared prefix blocks are copied too — on restore the sequence gets
@@ -648,17 +650,23 @@ class PagedSlotCache:
         self.table[slot, :state.num_pages] = blocks
         b_idx = jnp.asarray(blocks, jnp.int32)
         s_idx = jnp.asarray([slot], jnp.int32)
+
+        def restore(dst, src, idx):
+            # back to device memory under the pool's own sharding, so the
+            # updated leaf keeps the sharding, memory kind and commitment
+            # the decode step was compiled for
+            src = jax.device_put(src, dst.sharding)
+            return dst.at[:, idx].set(src.astype(dst.dtype))
+
         new = []
         for i, is_attn in enumerate(self._attn):
             if is_attn:
-                new.append({key: self.data[i][key].at[:, b_idx].set(
-                    jnp.asarray(state.blocks[i][key]).astype(
-                        self.data[i][key].dtype))
-                    for key in ("k", "v")})
+                new.append({key: restore(self.data[i][key],
+                                         state.blocks[i][key], b_idx)
+                            for key in ("k", "v")})
             else:
                 new.append(jax.tree.map(
-                    lambda dst, src: dst.at[:, s_idx].set(
-                        jnp.asarray(src).astype(dst.dtype)),
+                    lambda dst, src: restore(dst, src, s_idx),
                     self.data[i], state.blocks[i]))
         self.data = tuple(new)
         self._commit()

@@ -33,8 +33,9 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
-from repro.models import (decode_step, prefill, prefill_with_past,
-                          prefill_with_prefix)
+from repro.models import (decode_step, init_params, prefill,
+                          prefill_with_past, prefill_with_prefix)
+from repro.models.model import cast_params
 from repro.parallel import context as pctx
 from repro.serving.cache import PagedSlotCache, SlotCache
 from repro.serving.utils import EngineStats, pow2_bucket
@@ -159,6 +160,23 @@ class ExecuteOutput:
     chunk_tokens: np.ndarray | None = None
 
 
+def init_serving_params(cfg: ModelConfig, key: jax.Array, mesh=None):
+    """Random serving weights, made on the device(s) in the compute dtype.
+
+    One jitted init casts as it creates, so no float32 copy of the model
+    is ever resident: the values equal ``init_params`` cast to
+    ``cfg.dtype``.  On a mesh the init's ``out_shardings`` are the
+    serving partition rules, so each device creates only its own shard
+    and no device ever holds the whole model."""
+    shardings = None
+    if mesh is not None:
+        from repro.parallel.sharding import partition_params, to_named
+        shardings = to_named(mesh, partition_params(cfg, mesh))
+    init = jax.jit(lambda k: cast_params(init_params(cfg, k), cfg.dtype),
+                   out_shardings=shardings)
+    return init(key)
+
+
 def _compiled_count(fn) -> int | None:
     """Compile count of one jitted dispatch (None when the running jax
     can't report it, or the fn was monkeypatched by a test spy)."""
@@ -199,6 +217,10 @@ class ModelRunner:
         self.stats = stats if stats is not None else EngineStats()
         self.attn_only = all(m == "attn" for m, _ in cfg.pattern)
         self._sample = _make_sampler(cfg, self.max_top_k)
+        # serving holds its weights in the compute dtype: cast once here
+        # (a no-op for weights from init_serving_params), so no dispatch
+        # carries a per-call cast or a temporary copy of the weights
+        params = cast_params(params, cfg.dtype)
 
         if mesh is not None:
             from repro.parallel.sharding import (guard_spec, partition_caches,

@@ -4,11 +4,13 @@ import os
 import subprocess
 import sys
 
+import jax
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def run_module(mod, *args, timeout=900):
-    env = dict(os.environ)
+def run_module(mod, *args, timeout=900, **extra_env):
+    env = dict(os.environ, **extra_env)
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     out = subprocess.run([sys.executable, "-m", mod, *args],
                          capture_output=True, text=True, env=env,
@@ -36,9 +38,36 @@ def test_train_cli_resume(tmp_path):
 
 
 def test_serve_cli_generates(tmp_path):
-    out = run_module("repro.launch.serve", "--arch", "qwen3-4b",
+    out = run_module("repro.launch.serve", "--arch", "qwen3-4b", "--reduce",
                      "--batch", "2", "--prompt-len", "4", "--max-new", "4")
     assert "generated" in out
+
+
+def test_serve_cli_compile_cache_follows_env(tmp_path):
+    """JAX_COMPILATION_CACHE_DIR, when set, is where the CLI's compiles
+    land (every compile is cached here: the minimum compile time is 0)."""
+    cache = tmp_path / "jax_cache"
+    run_module("repro.launch.serve", "--arch", "qwen3-4b", "--reduce",
+               "--batch", "1", "--prompt-len", "4", "--max-new", "2",
+               JAX_COMPILATION_CACHE_DIR=str(cache),
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0")
+    assert cache.is_dir() and any(cache.iterdir())
+
+
+def test_compile_cache_defaults_to_checkout(monkeypatch):
+    """With the variable unset the cache sits at one fixed path inside the
+    checkout, whatever the working directory."""
+    from repro.launch.compile_cache import use_compile_cache
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.chdir("/")
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        path = use_compile_cache()
+        assert path == os.path.join(REPO, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
 
 
 def test_dryrun_cli_single_cell():

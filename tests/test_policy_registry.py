@@ -161,12 +161,13 @@ def test_registry_extensible_with_custom_kind():
 def test_kernel_dispatch_through_registry():
     """use_kernel routes through the registered Pallas backend (interpret
     mode on CPU) and matches the jnp reference path."""
-    rule = Rule(kind="butterfly", block_size=8, use_kernel=True)
-    lin = Linear(rule, 32, 32, site="mlp")
+    rule = Rule(kind="butterfly", block_size=128, use_kernel=True)
+    lin = Linear(rule, 256, 256, site="mlp")
     entry = registry.get_factorization("butterfly")
     assert entry.kernel_apply is not None  # kernels attached on demand
+    assert entry.kernel_supports(lin.spec)  # lane-aligned: the kernel path
     params = lin.init(jax.random.PRNGKey(0))
-    x = jax.random.normal(jax.random.PRNGKey(1), (4, 32))
+    x = jax.random.normal(jax.random.PRNGKey(1), (4, 256))
     got = lin(params, x)
     want = lin.spec.apply(params, x)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -176,10 +177,11 @@ def test_kernel_dispatch_through_registry():
 def test_kernel_path_is_differentiable():
     """use_kernel rules train: kernel forward, reference backward — grads
     match the pure-jnp path within kernel tolerance."""
-    x = jax.random.normal(jax.random.PRNGKey(1), (4, 32))
-    k_lin = Linear(Rule(kind="butterfly", block_size=8, use_kernel=True),
-                   32, 32, site="mlp")
-    r_lin = Linear(Rule(kind="butterfly", block_size=8), 32, 32, site="mlp")
+    x = jax.random.normal(jax.random.PRNGKey(1), (4, 256))
+    k_lin = Linear(Rule(kind="butterfly", block_size=128, use_kernel=True),
+                   256, 256, site="mlp")
+    r_lin = Linear(Rule(kind="butterfly", block_size=128), 256, 256,
+                   site="mlp")
     params = k_lin.init(jax.random.PRNGKey(0))
     gk = jax.grad(lambda p: (k_lin(p, x) ** 2).sum())(params)
     gr = jax.grad(lambda p: (r_lin(p, x) ** 2).sum())(params)
@@ -189,9 +191,12 @@ def test_kernel_path_is_differentiable():
 
 
 def test_kernel_supports_gating_falls_back():
-    """Blocks below the kernel threshold use the jnp path without error."""
+    """Blocks the kernel does not compile for (not lane-aligned) take the
+    jnp path, on every platform, without error."""
     rule = Rule(kind="butterfly", block_size=4, use_kernel=True)
     lin = Linear(rule, 32, 32, site="mlp")
+    assert not registry.get_factorization("butterfly").kernel_supports(
+        lin.spec)
     params = lin.init(jax.random.PRNGKey(0))
     y = lin(params, jnp.ones((2, 32)))
     assert y.shape == (2, 32)

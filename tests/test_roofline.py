@@ -3,10 +3,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+import pytest
+
 from repro.roofline.analysis import (
     CollectiveStats,
     Roofline,
     parse_collectives,
+    peaks_for,
 )
 from repro.roofline.hlo_cost import hlo_cost, parse_module
 
@@ -79,7 +82,8 @@ def test_parse_module_finds_computations():
 
 
 def test_roofline_terms_and_dominance():
-    r = Roofline(dot_flops=197e12, ew_flops=0.0, dot_bytes=819e9 / 2,
+    r = Roofline(peaks=peaks_for("TPU v5 lite"),
+                 dot_flops=197e12, ew_flops=0.0, dot_bytes=819e9 / 2,
                  buffer_bytes=0.0, collective_bytes_per_device=0.0,
                  collective_breakdown={}, collective_counts={})
     np.testing.assert_allclose(r.compute_s, 1.0)
@@ -88,6 +92,12 @@ def test_roofline_terms_and_dominance():
     assert r.compute_fraction == 1.0
     d = r.to_dict()
     assert d["dominant"] == "compute"
+
+
+def test_unknown_device_kind_raises():
+    """A device with no entry in the peak table is an error, not v5e."""
+    with pytest.raises(KeyError, match="no peak rates"):
+        peaks_for("TPU v4")
 
 
 def test_legacy_collective_parser():

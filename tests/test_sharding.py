@@ -146,8 +146,8 @@ def test_compressed_psum_multidevice():
         from jax.experimental.shard_map import shard_map
         from repro.optim.compression import compressed_psum, ef_init
 
-        from repro.launch.mesh import make_mesh_compat
-        mesh = make_mesh_compat((8,), ('dp',))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ('dp',))
         g = jax.random.normal(jax.random.PRNGKey(0), (8, 128))
         ef = jnp.zeros((8, 128))
 
