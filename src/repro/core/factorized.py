@@ -129,6 +129,10 @@ class Linear:
                                          bias, dtype)
         self.site = site
         self.batch_dims = tuple(batch_dims)
+        # the named scope the site's ops carry in compiled programs (and so
+        # in device traces): fact.<kind>.<site>, or dense.<site>
+        self.scope = f"dense.{site}" if self.rule.kind == "dense" \
+            else f"fact.{self.rule.kind}.{site}"
         if self.rule.use_kernel:
             # attach Pallas backends to the registry before the first apply
             registry.ensure_kernels_registered()
@@ -178,4 +182,5 @@ class Linear:
         apply = self._apply_one
         for _ in self.batch_dims:
             apply = jax.vmap(apply)
-        return apply(params, x)
+        with jax.named_scope(self.scope):
+            return apply(params, x)

@@ -341,6 +341,10 @@ def stats_payload(engine: Engine, state: ServerState) -> dict:
             "chunk_size": engine.chunk_size,
             "chunk_dispatches": st.chunk_dispatches,
             "max_decode_stall_s": st.max_decode_stall,
+            # admission waves that held decode-ready slots back, and
+            # their summed wall time
+            "prefill_stall_waves": st.prefill_stall_waves,
+            "prefill_stall_time_s": st.prefill_stall_time,
             # one compile counter per dispatch kind: decode must stay at 1
             # forever; prefill/prefix grow one per pow2 shape bucket, so a
             # drift here means the bucketing regressed
@@ -573,7 +577,9 @@ def run_batch(args, engine: Engine, cfg) -> None:
                  spec["mean_run_length"] or 0.0,
                  spec["verify_time_s"], spec["verify_dispatches"],
                  spec["draft_time_s"], spec["draft_decode_dispatches"])
-    log.info("max decode stall: %.4f s", st.max_decode_stall)
+    log.info("max decode stall: %.4f s; %d admission waves held decode "
+             "for %.4f s", st.max_decode_stall, st.prefill_stall_waves,
+             st.prefill_stall_time)
     for line in _latency_lines(outputs):
         log.info("%s", line)
     log.info("sample %s: %s", outputs[0].request_id,

@@ -1,5 +1,11 @@
 """GQA attention: fused (factorizable) QKV, RoPE/M-RoPE, qk-norm, chunked
-flash-style training attention, and KV-cache decode."""
+flash-style training attention, and KV-cache decode.
+
+Compiled programs carry named scopes for the parts of attention a device
+trace should tell apart: ``attn.rope``, ``attn.kv_write`` (new K/V into the
+cache or pool), ``attn.kv_gather`` (a paged view gathered from the pool)
+and ``attn.core`` (scores, mask, softmax, values).  The projections carry
+their own (``Linear``: ``fact.<kind>.<site>`` / ``dense.<site>``)."""
 from __future__ import annotations
 
 from typing import Any
@@ -52,12 +58,13 @@ def _project_qkv(params, cfg: ModelConfig, x: jax.Array, positions: jax.Array):
     if cfg.qk_norm:
         q = rms_norm(q, params["q_norm"], cfg.norm_eps)
         k = rms_norm(k, params["k_norm"], cfg.norm_eps)
-    if cfg.mrope:
-        q = apply_mrope(q, positions, cfg.rope_theta)
-        k = apply_mrope(k, positions, cfg.rope_theta)
-    else:
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
+    with jax.named_scope("attn.rope"):
+        if cfg.mrope:
+            q = apply_mrope(q, positions, cfg.rope_theta)
+            k = apply_mrope(k, positions, cfg.rope_theta)
+        else:
+            q = apply_rope(q, positions, cfg.rope_theta)
+            k = apply_rope(k, positions, cfg.rope_theta)
     return q, k, v
 
 
@@ -178,10 +185,11 @@ def chunked_causal_attention(
     q: (B, S, Hq, hd); k, v: (B, S, Hkv, hd) with Hq % Hkv == 0.
     """
     s = q.shape[1]
-    if s <= 2 * chunk:  # small enough: direct masked attention
-        return _direct_attention(q, k, v)
-    assert s % chunk == 0, (s, chunk)
-    return _flash_attention(q, k, v, chunk)
+    with jax.named_scope("attn.core"):
+        if s <= 2 * chunk:  # small enough: direct masked attention
+            return _direct_attention(q, k, v)
+        assert s % chunk == 0, (s, chunk)
+        return _flash_attention(q, k, v, chunk)
 
 
 def attn_forward(
@@ -209,14 +217,15 @@ def _decode_attention(params: dict, cfg: ModelConfig, q: jax.Array,
     t = k.shape[1]
     hq, hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.hd
     g = hq // hkv
-    qh = (q * hd ** -0.5).reshape(b, 1, hkv, g, hd)
-    scores = jnp.einsum("bqkgh,btkh->bkgqt", qh.astype(jnp.float32),
-                        k.astype(jnp.float32))  # (B,kv,g,1,T)
-    valid = jnp.arange(t)[None, :] <= pos[:, None]  # (B, T)
-    scores = jnp.where(valid[:, None, None, None, :], scores, NEG_INF)
-    p = jax.nn.softmax(scores, axis=-1)
-    o = jnp.einsum("bkgqt,btkh->bqkgh", p, v.astype(jnp.float32))
-    o = o.reshape(b, 1, hq * hd).astype(out_dtype)
+    with jax.named_scope("attn.core"):
+        qh = (q * hd ** -0.5).reshape(b, 1, hkv, g, hd)
+        scores = jnp.einsum("bqkgh,btkh->bkgqt", qh.astype(jnp.float32),
+                            k.astype(jnp.float32))  # (B,kv,g,1,T)
+        valid = jnp.arange(t)[None, :] <= pos[:, None]  # (B, T)
+        scores = jnp.where(valid[:, None, None, None, :], scores, NEG_INF)
+        p = jax.nn.softmax(scores, axis=-1)
+        o = jnp.einsum("bkgqt,btkh->bqkgh", p, v.astype(jnp.float32))
+        o = o.reshape(b, 1, hq * hd).astype(out_dtype)
     _, out_lin = _linears(cfg)
     return out_lin(params["out"], o)
 
@@ -238,10 +247,11 @@ def attn_decode(
     q, k_new, v_new = _project_qkv(params, cfg, x, positions)
 
     rows = jnp.arange(b)
-    k = cache["k"].at[rows, pos].set(
-        k_new[:, 0].astype(cache["k"].dtype), mode="drop")
-    v = cache["v"].at[rows, pos].set(
-        v_new[:, 0].astype(cache["v"].dtype), mode="drop")
+    with jax.named_scope("attn.kv_write"):
+        k = cache["k"].at[rows, pos].set(
+            k_new[:, 0].astype(cache["k"].dtype), mode="drop")
+        v = cache["v"].at[rows, pos].set(
+            v_new[:, 0].astype(cache["v"].dtype), mode="drop")
 
     y = _decode_attention(params, cfg, q, k, v, pos, x.dtype)
     return y, {"k": k, "v": v}
@@ -274,17 +284,21 @@ def attn_decode_paged(
         positions = jnp.broadcast_to(positions[..., None], (b, 1, 3))
     q, k_new, v_new = _project_qkv(params, cfg, x, positions)
 
-    bid = jnp.take_along_axis(
-        page_table, (pos // page_size)[:, None], axis=1)[:, 0]  # (B,)
-    off = pos % page_size
-    k_pool = cache["k"].at[bid, off].set(k_new[:, 0].astype(cache["k"].dtype))
-    v_pool = cache["v"].at[bid, off].set(v_new[:, 0].astype(cache["v"].dtype))
+    with jax.named_scope("attn.kv_write"):
+        bid = jnp.take_along_axis(
+            page_table, (pos // page_size)[:, None], axis=1)[:, 0]  # (B,)
+        off = pos % page_size
+        k_pool = cache["k"].at[bid, off].set(
+            k_new[:, 0].astype(cache["k"].dtype))
+        v_pool = cache["v"].at[bid, off].set(
+            v_new[:, 0].astype(cache["v"].dtype))
 
     hkv, hd = cfg.num_kv_heads, cfg.hd
-    k = k_pool[page_table].reshape(b, -1, hkv, hd)[:, :kv_len]
-    v = v_pool[page_table].reshape(b, -1, hkv, hd)[:, :kv_len]
-    k = pctx.constrain(k, "dp", None, None, None)
-    v = pctx.constrain(v, "dp", None, None, None)
+    with jax.named_scope("attn.kv_gather"):
+        k = k_pool[page_table].reshape(b, -1, hkv, hd)[:, :kv_len]
+        v = v_pool[page_table].reshape(b, -1, hkv, hd)[:, :kv_len]
+        k = pctx.constrain(k, "dp", None, None, None)
+        v = pctx.constrain(v, "dp", None, None, None)
 
     y = _decode_attention(params, cfg, q, k, v, pos, x.dtype)
     return y, {"k": k_pool, "v": v_pool}
@@ -318,10 +332,11 @@ def attn_prefill_paged_past(
     hq, hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.hd
     q, k, v = _project_qkv(params, cfg, x, positions)
 
-    kp = cache["k"][page_table].reshape(b, -1, hkv, hd)
-    vp = cache["v"][page_table].reshape(b, -1, hkv, hd)
-    kp = pctx.constrain(kp, "dp", None, None, None)
-    vp = pctx.constrain(vp, "dp", None, None, None)
+    with jax.named_scope("attn.kv_gather"):
+        kp = cache["k"][page_table].reshape(b, -1, hkv, hd)
+        vp = cache["v"][page_table].reshape(b, -1, hkv, hd)
+        kp = pctx.constrain(kp, "dp", None, None, None)
+        vp = pctx.constrain(vp, "dp", None, None, None)
     y = _prefill_past_attention(params, cfg, q, k, v, kp, vp,
                                 prefix_lens, x.dtype)
     return y, {"k": k.astype(cfg.dtype), "v": v.astype(cfg.dtype)}
@@ -363,23 +378,24 @@ def _prefill_past_attention(params: dict, cfg: ModelConfig, q: jax.Array,
     b, s = q.shape[0], q.shape[1]
     hq, hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.hd
     n_pref = kp.shape[1]
-    kf = jnp.concatenate([kp, k.astype(kp.dtype)], axis=1)  # (B, T, Hkv, hd)
-    vf = jnp.concatenate([vp, v.astype(vp.dtype)], axis=1)
+    with jax.named_scope("attn.core"):
+        kf = jnp.concatenate([kp, k.astype(kp.dtype)], axis=1)  # (B,T,Hkv,hd)
+        vf = jnp.concatenate([vp, v.astype(vp.dtype)], axis=1)
 
-    t = n_pref + s
-    g = hq // hkv
-    qh = (q * hd ** -0.5).reshape(b, s, hkv, g, hd)
-    scores = jnp.einsum("bqkgh,btkh->bkgqt", qh.astype(jnp.float32),
-                        kf.astype(jnp.float32))  # (B,kv,g,S,T)
-    tpos = jnp.arange(t)
-    causal = (tpos[None, :] - n_pref) <= jnp.arange(s)[:, None]  # (S, T)
-    valid = jnp.where((tpos < n_pref)[None, None, :],
-                      tpos[None, None, :] < prefix_lens[:, None, None],
-                      causal[None])  # (B, S, T)
-    scores = jnp.where(valid[:, None, None], scores, NEG_INF)
-    p = jax.nn.softmax(scores, axis=-1)
-    o = jnp.einsum("bkgqt,btkh->bqkgh", p, vf.astype(jnp.float32))
-    o = o.reshape(b, s, hq * hd).astype(out_dtype)
+        t = n_pref + s
+        g = hq // hkv
+        qh = (q * hd ** -0.5).reshape(b, s, hkv, g, hd)
+        scores = jnp.einsum("bqkgh,btkh->bkgqt", qh.astype(jnp.float32),
+                            kf.astype(jnp.float32))  # (B,kv,g,S,T)
+        tpos = jnp.arange(t)
+        causal = (tpos[None, :] - n_pref) <= jnp.arange(s)[:, None]  # (S,T)
+        valid = jnp.where((tpos < n_pref)[None, None, :],
+                          tpos[None, None, :] < prefix_lens[:, None, None],
+                          causal[None])  # (B, S, T)
+        scores = jnp.where(valid[:, None, None], scores, NEG_INF)
+        p = jax.nn.softmax(scores, axis=-1)
+        o = jnp.einsum("bkgqt,btkh->bqkgh", p, vf.astype(jnp.float32))
+        o = o.reshape(b, s, hq * hd).astype(out_dtype)
     _, out_lin = _linears(cfg)
     return out_lin(params["out"], o)
 

@@ -6,10 +6,12 @@ import jax.numpy as jnp
 
 
 def rms_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-5) -> jax.Array:
-    dtype = x.dtype
-    x = x.astype(jnp.float32)
-    var = jnp.mean(x * x, axis=-1, keepdims=True)
-    return (x * jax.lax.rsqrt(var + eps) * scale.astype(jnp.float32)).astype(dtype)
+    with jax.named_scope("norm"):
+        dtype = x.dtype
+        x = x.astype(jnp.float32)
+        var = jnp.mean(x * x, axis=-1, keepdims=True)
+        return (x * jax.lax.rsqrt(var + eps)
+                * scale.astype(jnp.float32)).astype(dtype)
 
 
 def init_rms_norm(d: int, dtype=jnp.float32) -> jax.Array:

@@ -145,7 +145,8 @@ def cast_params(params, dtype):
 def _embed_inputs(params: dict, cfg: ModelConfig, inputs: jax.Array) -> jax.Array:
     if cfg.input_mode == "tokens":
         tok = jnp.clip(inputs, 0, cfg.vocab_size - 1)
-        x = jnp.take(params["embed"], tok, axis=0)
+        with jax.named_scope("embed"):
+            x = jnp.take(params["embed"], tok, axis=0)
     else:
         x = inputs  # precomputed frontend embeddings (B, S, d)
     return x.astype(cfg.dtype)
@@ -182,7 +183,8 @@ def forward(params: dict, cfg: ModelConfig, inputs: jax.Array,
         x, caches = inner(x)
         return x, caches
 
-    x, caches = jax.lax.scan(period_body, x, params["periods"])
+    with jax.named_scope("layers"):
+        x, caches = jax.lax.scan(period_body, x, params["periods"])
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = _head_linear(cfg)(params["head"], x)
     if return_caches:
@@ -222,7 +224,13 @@ def decode_step(params: dict, cfg: ModelConfig, inputs: jax.Array,
             new.append(nc)
         return x, tuple(new)
 
-    x, new_caches = jax.lax.scan(period_body, x, (params["periods"], caches))
+    # the "layers" scope holds the scan's own work — per-layer slices of the
+    # stacked weights and caches, the stacked caches it writes out — and
+    # whatever in a layer no inner scope (fact.*, dense.*, attn.*, norm)
+    # claims, such as the residual adds
+    with jax.named_scope("layers"):
+        x, new_caches = jax.lax.scan(period_body, x,
+                                     (params["periods"], caches))
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = _head_linear(cfg)(params["head"], x)
     return logits, new_caches
@@ -361,7 +369,9 @@ def prefill_with_prefix(params: dict, cfg: ModelConfig, inputs: jax.Array,
             tails.append(kv)
         return x, tuple(tails)
 
-    x, tails = jax.lax.scan(period_body, x, (params["periods"], paged_caches))
+    with jax.named_scope("layers"):
+        x, tails = jax.lax.scan(period_body, x,
+                                (params["periods"], paged_caches))
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = _head_linear(cfg)(params["head"], x)
     return logits, tails
@@ -418,7 +428,8 @@ def prefill_with_past(params: dict, cfg: ModelConfig, inputs: jax.Array,
             tails.append(kv)
         return x, tuple(tails)
 
-    x, tails = jax.lax.scan(period_body, x, (params["periods"], caches))
+    with jax.named_scope("layers"):
+        x, tails = jax.lax.scan(period_body, x, (params["periods"], caches))
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = _head_linear(cfg)(params["head"], x)
     return logits, tails

@@ -17,12 +17,15 @@ re-entrant step loop the monolithic Engine exposed; ``Engine`` in
 :mod:`repro.serving.engine` is now a thin facade over this class.  Every
 wall-clock second a ``step()`` spends OUTSIDE the runner's compiled
 dispatches accumulates into ``stats.host_time`` — the host-vs-device
-split ``/stats`` reports.
+split ``/stats`` reports.  Each step, scheduling pass, reclaim and event
+emission runs under a span (``serve.step``, ``serve.schedule``,
+``serve.reclaim``, ``serve.emit``; :mod:`repro.serving.tracing`).
 """
 from __future__ import annotations
 
 import time
 
+from repro.serving import tracing
 from repro.serving.cache import PoolExhausted
 from repro.serving.events import StepEvent
 from repro.serving.executor import Executor
@@ -96,6 +99,12 @@ class EngineCore:
         # completion, None while no admitted sequence is decode-ready (the
         # gap only counts as a stall if someone was waiting to decode)
         self._last_decode_done: float | None = None
+        # the current step's index, kind (tracing.KIND_*) and row count:
+        # meta of its serve.step span; the kind also decides whether the
+        # step counts as a prefill stall
+        self._step_index = 0
+        self._kind = tracing.KIND_DECODE
+        self._rows = 0
         # speculative decoding (DESIGN.md section 16): the drafter runs the
         # executor's small dense draft model k tokens ahead per slot, the
         # verifier scores every slot's proposals in ONE batched target
@@ -191,28 +200,52 @@ class EngineCore:
         when idle)."""
         if not self.scheduler.has_work:
             return []
-        t0 = time.perf_counter()
         dev0 = self.stats.device_time
+        # stall accounting arms only while someone could decode: a gap
+        # with no decode-ready sequence (pure prefill warmup, idle) is not
+        # a stall
+        ready = any(s.tokens and s.swap_state is None
+                    for s in self.scheduler.active.values())
+        if not ready:
+            self._last_decode_done = None
+        self._step_index += 1
+        self._kind, self._rows = tracing.KIND_DECODE, 0
+        step = tracing.span("serve.step", step=self._step_index,
+                            decode_ready=int(ready))
         try:
-            self._preempted_now = []
-            # stall accounting arms only while someone could decode: a gap
-            # with no decode-ready sequence (pure prefill warmup, idle)
-            # is not a stall
-            if not any(s.tokens and s.swap_state is None
-                       for s in self.scheduler.active.values()):
-                self._last_decode_done = None
-            # token counts BEFORE the step body: a speculative verify
-            # commits several tokens per sequence per step (and commits
-            # BEFORE any page-pressure preemption, so even a preempted
-            # sequence may have grown) — every path's events come from
-            # this one before/after delta, one event per new token
-            before = {rid: len(s.tokens) for rid, s in self._live.items()}
-            if self.speculative:
-                progressed = self._step_speculative()
-            elif self.chunk_size is not None:
-                progressed = self._step_chunked()
-            else:
-                progressed = self._step_legacy()
+            with step:
+                events = self._step_events()
+                step.note(kind=self._kind, rows=self._rows)
+            if ready and self._kind in (tracing.KIND_PREFILL,
+                                        tracing.KIND_PREFIX):
+                # an admission wave that held every decode-ready sequence
+                # back for its whole step
+                self.stats.prefill_stall_time += step.seconds
+                self.stats.prefill_stall_waves += 1
+            return events
+        finally:
+            # whatever this step spent outside the runner's dispatch
+            # windows is host overhead: scheduling, array staging, cache
+            # bookkeeping, event emission
+            dev = self.stats.device_time - dev0
+            self.stats.host_time += max(0.0, step.seconds - dev)
+
+    def _step_events(self) -> list[StepEvent]:
+        """The step body and its events, one per new token."""
+        self._preempted_now = []
+        # token counts BEFORE the step body: a speculative verify commits
+        # several tokens per sequence per step (and commits BEFORE any
+        # page-pressure preemption, so even a preempted sequence may have
+        # grown) — every path's events come from this one before/after
+        # delta, one event per new token
+        before = {rid: len(s.tokens) for rid, s in self._live.items()}
+        if self.speculative:
+            progressed = self._step_speculative()
+        elif self.chunk_size is not None:
+            progressed = self._step_chunked()
+        else:
+            progressed = self._step_legacy()
+        with tracing.span("serve.emit"):
             events = []
             for s in progressed:
                 n = len(s.tokens)
@@ -225,20 +258,23 @@ class EngineCore:
             events += [StepEvent(rid, token=None, index=None, preempted=True)
                        for rid in self._preempted_now]
             self._retire_finished()
-            return events
-        finally:
-            # whatever this step spent outside the runner's dispatch
-            # windows is host overhead: scheduling, array staging, cache
-            # bookkeeping, event emission
-            dev = self.stats.device_time - dev0
-            self.stats.host_time += max(
-                0.0, (time.perf_counter() - t0) - dev)
+        return events
+
+    def _admit(self) -> list[Sequence]:
+        """``Scheduler.admit`` under the ``serve.schedule`` span, which
+        carries the wave's admit_seqno range."""
+        with tracing.span("serve.schedule") as sp:
+            admitted = self.scheduler.admit()
+            if admitted:
+                sp.note(first=admitted[0].admit_seqno,
+                        last=admitted[-1].admit_seqno)
+        return admitted
 
     def _step_legacy(self) -> list:
         """The admit-OR-decode step body (``chunk_size`` unset): byte-for-
         byte the pre-chunking behavior — one admission wave or one decode
         dispatch per call, never both."""
-        admitted = self.scheduler.admit()
+        admitted = self._admit()
         if admitted:
             before = {s.request_id: len(s.tokens) for s in admitted}
             self._prefill_admitted(admitted)
@@ -264,7 +300,7 @@ class EngineCore:
         commits.  Admission waves additionally prefill the DRAFT cache for
         the admitted sequences (fresh and resumed alike — recompute rebuilds
         both models' state), so a round never mixes prefill and verify."""
-        admitted = self.scheduler.admit()
+        admitted = self._admit()
         if admitted:
             before = {s.request_id: len(s.tokens) for s in admitted}
             self._prefill_admitted(admitted)
@@ -278,6 +314,7 @@ class EngineCore:
             raise RuntimeError(
                 "scheduler stalled: waiting requests but nothing "
                 "active")
+        self._kind, self._rows = tracing.KIND_VERIFY, len(active)
         proposals = self.drafter.propose(active)
         progressed = self.verifier.verify_and_commit(active, proposals)
         # a verify round IS the step's decode dispatch for stall purposes:
@@ -304,7 +341,13 @@ class EngineCore:
         released like any other pages (its cursor resets to 0 for
         drop-and-recompute, survives for swap restore) and the plan rows
         are re-filtered by state after every reclaim."""
-        plan = self.scheduler.plan_step()
+        with tracing.span("serve.schedule") as sp:
+            plan = self.scheduler.plan_step()
+            if plan.admitted:
+                sp.note(first=plan.admitted[0].admit_seqno,
+                        last=plan.admitted[-1].admit_seqno)
+        self._kind = tracing.KIND_MIXED
+        self._rows = len(plan.decode) + len(plan.chunks)
         if not plan.admitted and not self.scheduler.active:
             raise RuntimeError(
                 "scheduler stalled: waiting requests but nothing active")
@@ -342,19 +385,7 @@ class EngineCore:
         # decode page growth keeps legacy semantics: it may preempt ANY
         # active row — including a mid-prefill one, whose already-written
         # chunk pages are simply released (recompute-from-progress later)
-        decode = list(plan.decode)
-        if decode:
-            for s in decode:
-                while s.state is SequenceState.RUNNING:
-                    try:
-                        self.executor.ensure_mapped(
-                            s.slot, self.executor.position(s.slot))
-                        break
-                    except PoolExhausted as e:
-                        if not self._reclaim(e.shortfall, frozenset()):
-                            raise
-            decode = [s for s in decode
-                      if s.state is SequenceState.RUNNING]
+        decode = self._grow_tables(list(plan.decode))
         chunks = [(s, n) for s, n in plan.chunks
                   if s.state is SequenceState.RUNNING]
         if not decode and not chunks:
@@ -373,10 +404,7 @@ class EngineCore:
         progressed = []
         if decode:
             self._note_decode_dispatch()
-            for s in decode:
-                s.append_token(int(out.tokens[s.slot]), self.eos_id)
-                s.prefill_progress = s.prefill_len
-                progressed.append(s)
+            progressed += self._append_decoded(decode, out.tokens)
         # advance cursors; a sequence whose cursor reaches prefill_len is
         # done prefilling — its final chunk's sample IS its first token
         # (recorded before the tail scatter, like the prefix path: this is
@@ -472,6 +500,7 @@ class EngineCore:
         wave's charges, so after reclaiming everyone else the wave always
         fits (the no-deadlock argument in DESIGN.md section 13)."""
         protect = frozenset(s.request_id for s in admitted)
+        self._rows = len(admitted)
         hits, misses = [], []
         for s in admitted:
             if s.swap_state is not None:
@@ -480,6 +509,8 @@ class EngineCore:
                 hits.append(s)
             else:
                 misses.append(s)
+        self._kind = tracing.KIND_PREFIX if hits and not misses \
+            else tracing.KIND_PREFILL
         if misses:
             lengths = {s.prefill_len for s in misses}
             if self.executor.attn_only or len(lengths) == 1:
@@ -623,12 +654,24 @@ class EngineCore:
         ``active``) and retries; preempted sequences drop out of the
         dispatch (their slots ride along idle) and resume later."""
         if self.page_size is not None:
-            # grow page tables before the dispatch: each active slot whose
-            # write position crosses into an unmapped block gets one from
-            # the free list.  At overcommit 1.0 admission reserved the
-            # worst case and this cannot fail; above it PoolExhausted
-            # triggers reclaim.  Values-only change — never a recompile.
-            for s in active:
+            active = self._grow_tables(active)
+            if not active:
+                return []
+        self._rows = len(active)
+        out = self.executor.execute(ExecuteInput(
+            kind="decode", slots=tuple(s.slot for s in active)))
+        self._note_decode_dispatch()
+        return self._append_decoded(active, out.tokens)
+
+    def _grow_tables(self, rows: list[Sequence]) -> list[Sequence]:
+        """Grow page tables before a decode dispatch: each row whose write
+        position crosses into an unmapped block gets one from the free
+        list.  At overcommit 1.0 admission reserved the worst case and
+        this cannot fail; above it PoolExhausted triggers reclaim (which
+        may preempt ANY active row).  Values-only change — never a
+        recompile.  Returns the rows still running."""
+        with tracing.span("serve.cache.ensure_mapped"):
+            for s in rows:
                 while s.state is SequenceState.RUNNING:
                     try:
                         self.executor.ensure_mapped(
@@ -637,19 +680,18 @@ class EngineCore:
                     except PoolExhausted as e:
                         if not self._reclaim(e.shortfall, frozenset()):
                             raise
-            active = [s for s in active
-                      if s.state is SequenceState.RUNNING]
-            if not active:
-                return []
-        out = self.executor.execute(ExecuteInput(
-            kind="decode", slots=tuple(s.slot for s in active)))
-        self._note_decode_dispatch()
-        for s in active:
-            s.append_token(int(out.tokens[s.slot]), self.eos_id)
-            # each appended token extends prefill_len by one cached
-            # position (the previous pending token); the cursor tracks it
-            s.prefill_progress = s.prefill_len
-        return active
+        return [s for s in rows if s.state is SequenceState.RUNNING]
+
+    def _append_decoded(self, rows: list[Sequence], tokens) -> list[Sequence]:
+        """Append each decode row's sampled token (slot-indexed)."""
+        with tracing.span("serve.emit"):
+            for s in rows:
+                s.append_token(int(tokens[s.slot]), self.eos_id)
+                # each appended token extends prefill_len by one cached
+                # position (the previous pending token); the cursor
+                # tracks it
+                s.prefill_progress = s.prefill_len
+        return rows
 
     # --------------------------------------------------------- preemption --
     def _reclaim(self, shortfall: int, protect: frozenset) -> bool:
@@ -659,17 +701,18 @@ class EngineCore:
         ``protect`` (it has the least KV to rebuild and its victimization
         cannot starve older work).  Returns False when nothing could be
         reclaimed — the caller's retry would loop forever, so it re-raises."""
-        freed = 0
-        if self.prefix is not None:
-            freed = self.prefix.evict(shortfall)
-            if freed >= shortfall:
-                return True
-        victims = [s for s in self.scheduler.active.values()
-                   if s.request_id not in protect]
-        if not victims:
-            return freed > 0
-        self._preempt(self._pick_victim(victims))
-        return True
+        with tracing.span("serve.reclaim"):
+            freed = 0
+            if self.prefix is not None:
+                freed = self.prefix.evict(shortfall)
+                if freed >= shortfall:
+                    return True
+            victims = [s for s in self.scheduler.active.values()
+                       if s.request_id not in protect]
+            if not victims:
+                return freed > 0
+            self._preempt(self._pick_victim(victims))
+            return True
 
     def _pick_victim(self, victims: list[Sequence]) -> Sequence:
         """Choose which running sequence to preempt.  Among the candidates,

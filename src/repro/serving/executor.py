@@ -338,7 +338,7 @@ class LocalExecutor(Executor):
                 draft_params, draft_cfg, max_len=spec.max_len,
                 num_slots=spec.num_slots, page_size=None,
                 mesh=mesh, dp=dp, tp=tp, max_top_k=spec.max_top_k,
-                stats=self.draft_stats)
+                stats=self.draft_stats, role="draft.")
 
     @property
     def cache(self):

@@ -19,13 +19,16 @@ are fully overwritten at the next insert; the page table is a replicated
 VALUE input, so table growth never retraces.  Prefill dispatch shapes are
 bucketed to powers of two so a long-lived runner compiles
 O(log slots x log max_len) prefill variants, not one per admission shape.
+
+Every dispatch runs under three spans (``serve.stage.<kind>``,
+``serve.launch.<kind>``, ``serve.wait.<kind>``) and every cache operation
+under ``serve.cache.<op>`` (:mod:`repro.serving.tracing`).
 """
 from __future__ import annotations
 
 import contextlib
 import dataclasses
 import math
-import time
 
 import jax
 import jax.numpy as jnp
@@ -36,6 +39,7 @@ from repro.configs.base import ModelConfig
 from repro.models import (decode_step, init_params, prefill,
                           prefill_with_past, prefill_with_prefix)
 from repro.models.model import cast_params
+import repro.serving.tracing as tracing
 from repro.parallel import context as pctx
 from repro.serving.cache import PagedSlotCache, SlotCache
 from repro.serving.utils import EngineStats, pow2_bucket
@@ -60,6 +64,10 @@ def _make_sampler(cfg: ModelConfig, max_top_k: int = MAX_TOP_K):
     kmax = min(max_top_k, v)
 
     def sample(logits, temps, top_k, seeds, positions):
+        with jax.named_scope("sample"):
+            return draw(logits, temps, top_k, seeds, positions)
+
+    def draw(logits, temps, top_k, seeds, positions):
         lg = logits[..., :v].astype(jnp.float32)
         n = lg.shape[0]
         greedy = jnp.argmax(lg, axis=-1).astype(jnp.int32)
@@ -193,7 +201,10 @@ class ModelRunner:
     and ``page_size=None`` selects the fixed-stripe :class:`SlotCache`.
     ``stats`` is the shared :class:`EngineStats` block — the runner
     accumulates the device-side fields (dispatch wall time + token/dispatch
-    counters) and the core the policy fields.
+    counters) and the core the policy fields.  ``role`` prefixes the
+    runner's span kinds (``"draft."`` for a speculative draft runner, whose
+    programs are then not published to trace reductions: they share the
+    target's program names).
     """
 
     def __init__(self, params, cfg: ModelConfig, *, max_len: int,
@@ -203,7 +214,8 @@ class ModelRunner:
                  tp: str | None = "model",
                  max_top_k: int = MAX_TOP_K,
                  spec_k: int = 0,
-                 stats: EngineStats | None = None):
+                 stats: EngineStats | None = None,
+                 role: str = ""):
         self.cfg = cfg
         self.max_len = max_len
         self.num_slots = num_slots
@@ -216,6 +228,17 @@ class ModelRunner:
         self.max_top_k = min(max_top_k, cfg.vocab_size)
         self.stats = stats if stats is not None else EngineStats()
         self.attn_only = all(m == "attn" for m, _ in cfg.pattern)
+        # span names, built once: (stage, launch, wait) per dispatch kind
+        self._spans = {k: tuple(f"serve.{phase}.{role}{k}"
+                                for phase in ("stage", "launch", "wait"))
+                       for k in ("decode", "prefill", "prefix", "mixed",
+                                 "verify")}
+        self._cache_spans = {op: f"serve.cache.{role}{op}" for op in (
+            "insert", "write_tails", "map_prefix", "cow", "alloc_tail",
+            "evict", "swap_out", "swap_in")}
+        self._decode_ran = False
+        if not role:
+            tracing.publish("jit_step_fn", self.decode_program_text)
         self._sample = _make_sampler(cfg, self.max_top_k)
         # serving holds its weights in the compute dtype: cast once here
         # (a no-op for weights from init_serving_params), so no dispatch
@@ -378,7 +401,8 @@ class ModelRunner:
             return self._execute_verify(inp)
         raise ValueError(f"unknown ExecuteInput kind {inp.kind!r}")
 
-    def _decode_dispatch(self, advance, live_rows=None) -> np.ndarray:
+    def _decode_dispatch(self, advance, live_rows=None,
+                         kind: str = "decode") -> np.ndarray:
         """One decode dispatch over ALL slots; rows named in ``advance``
         feed their sampled token back and move their position +1.
 
@@ -390,24 +414,19 @@ class ModelRunner:
         ride-along write would clobber their position-0 K/V.  A masked
         table is the same shape/dtype as the full one — a value change,
         never a recompile."""
-        if self.page_size is None:
-            table = None
-        elif live_rows is None:
-            table = self.cache.table_device()
-        else:
-            masked = np.zeros_like(self.cache.table)
-            rows = list(live_rows)
-            if rows:
-                masked[rows] = self.cache.table[rows]
-            table = jnp.asarray(masked)
-        t0 = time.perf_counter()
-        with self._trace_ctx():
-            nxt, self.cache.data = self._step(
-                self.params, self.cache.data, table, jnp.asarray(self._tok),
-                jnp.asarray(self._pos), jnp.asarray(self._temps),
-                jnp.asarray(self._topk), jnp.asarray(self._seeds))
-        nxt = np.asarray(nxt)
-        self.stats.decode_time += time.perf_counter() - t0
+        with tracing.span(self._spans[kind][0]):
+            if self.page_size is None or live_rows is None:
+                staged = self._decode_staging()
+            else:
+                masked = np.zeros_like(self.cache.table)
+                rows = list(live_rows)
+                if rows:
+                    masked[rows] = self.cache.table[rows]
+                staged = self._decode_staging(jnp.asarray(masked))
+        nxt, self.cache.data, seconds = self._dispatch(
+            kind, self._step, self.params, self.cache.data, *staged)
+        self._decode_ran = True
+        self.stats.decode_time += seconds
         self.stats.decode_steps += 1
         self.stats.decode_tokens += len(advance)
         for slot in advance:
@@ -423,13 +442,13 @@ class ModelRunner:
         decode writes land in the decode rows' (or scratch) pages, chunk
         tails return as caches for the core to scatter — so their order
         cannot change either result."""
-        nxt = self._decode_dispatch(inp.slots, live_rows=inp.slots) \
-            if inp.slots else None
+        nxt = self._decode_dispatch(inp.slots, live_rows=inp.slots,
+                                    kind="mixed") if inp.slots else None
         chunk_tokens = caches = None
         if inp.chunk_slots:
             chunk_tokens, caches = self._prefix_dispatch(
                 inp.chunk_slots, inp.tokens, inp.prefix_lens,
-                inp.temperatures, inp.top_ks, inp.seeds)
+                inp.temperatures, inp.top_ks, inp.seeds, kind="mixed")
             self.stats.chunk_dispatches += 1
         return ExecuteOutput(tokens=nxt, caches=caches,
                              chunk_tokens=chunk_tokens)
@@ -446,13 +465,23 @@ class ModelRunner:
         (only the ACCEPTED positions — commit is the core's call)."""
         if self.spec_k < 1:
             raise ValueError("runner built without spec_k; no verify fn")
+        with tracing.span(self._spans["verify"][0]):
+            args = self._stage_verify(inp)
+        out, tail_caches, seconds = self._dispatch(
+            "verify", self._verify, *args)
+        self.stats.verify_time += seconds
+        self.stats.verify_dispatches += 1
+        # committed tokens count as decode_tokens at the core (they ARE the
+        # output stream); the dispatch itself is accounted as verify_*
+        return ExecuteOutput(tokens=out, caches=tail_caches)
+
+    def _stage_verify(self, inp: ExecuteInput) -> list:
         ns, w = self.num_slots, self.spec_k + 1
         tails = np.zeros((ns, w), np.int32)
         plens = np.zeros((ns,), np.int32)
         temps = np.zeros((ns,), np.float32)
         topk = np.zeros((ns,), np.int32)
         seeds = np.zeros((ns,), np.uint32)
-        n_toks = 0
         for j, slot in enumerate(inp.slots):
             toks = inp.tokens[j]
             if len(toks) > w:
@@ -463,7 +492,6 @@ class ModelRunner:
             temps[slot] = inp.temperatures[j]
             topk[slot] = inp.top_ks[j]
             seeds[slot] = inp.seeds[j]
-            n_toks += len(toks)
 
         dpa = self._dpa()
         args = [self.params, self.cache.data]
@@ -473,15 +501,7 @@ class ModelRunner:
         args += [self._put(tails, P(dpa, None)), self._put(plens, P(dpa)),
                  self._put(temps, P(dpa)), self._put(topk, P(dpa)),
                  self._put(seeds, P(dpa))]
-        t0 = time.perf_counter()
-        with self._trace_ctx():
-            out, tail_caches = self._verify(*args)
-        jax.block_until_ready((out, tail_caches))
-        self.stats.verify_time += time.perf_counter() - t0
-        self.stats.verify_dispatches += 1
-        # committed tokens count as decode_tokens at the core (they ARE the
-        # output stream); the dispatch itself is accounted as verify_*
-        return ExecuteOutput(tokens=np.asarray(out), caches=tail_caches)
+        return args
 
     def _execute_prefill(self, inp: ExecuteInput) -> ExecuteOutput:
         """Batched full prefill.  (rows, width) bucket to powers of two so
@@ -492,8 +512,17 @@ class ModelRunner:
         max_len itself (or rows at num_slots) would reintroduce a non-pow2
         bucket whenever the cap isn't a power of two; prefill slices the
         decode-ready K/V back to max_len when width rounds past it."""
-        group_lens = [len(t) for t in inp.tokens]
-        width = max(group_lens)
+        with tracing.span(self._spans["prefill"][0]):
+            args, ragged = self._stage_prefill(inp)
+        first, caches, seconds = self._dispatch(
+            "prefill", self._prefill, *args, ragged=ragged)
+        self.stats.prefill_time += seconds
+        self.stats.prefill_tokens += sum(len(t) for t in inp.tokens)
+        self.stats.prefill_dispatches += 1
+        return ExecuteOutput(tokens=first, caches=caches)
+
+    def _stage_prefill(self, inp: ExecuteInput):
+        width = max(len(t) for t in inp.tokens)
         rows = len(inp.tokens)
         if self.attn_only:
             width = pow2_bucket(width, self.max_len)
@@ -510,20 +539,10 @@ class ModelRunner:
             topk[j] = inp.top_ks[j]
             seeds[j] = inp.seeds[j]
         ragged = bool((lens != width).any())
-
         dpa = self._dpa()
-        t0 = time.perf_counter()
-        with self._trace_ctx():
-            first, caches = self._prefill(
-                self.params, self._put(prompts, P(dpa, None)),
+        return (self.params, self._put(prompts, P(dpa, None)),
                 self._put(lens, P(dpa)), self._put(temps, P(dpa)),
-                self._put(topk, P(dpa)), self._put(seeds, P(dpa)),
-                ragged=ragged)
-        jax.block_until_ready((first, caches))
-        self.stats.prefill_time += time.perf_counter() - t0
-        self.stats.prefill_tokens += int(sum(group_lens))
-        self.stats.prefill_dispatches += 1
-        return ExecuteOutput(tokens=np.asarray(first), caches=caches)
+                self._put(topk, P(dpa)), self._put(seeds, P(dpa))), ragged
 
     def _execute_prefix(self, inp: ExecuteInput) -> ExecuteOutput:
         first, tail_caches = self._prefix_dispatch(
@@ -532,7 +551,7 @@ class ModelRunner:
         return ExecuteOutput(tokens=first, caches=tail_caches)
 
     def _prefix_dispatch(self, slots, tokens, prefix_lens,
-                         temperatures, top_ks, seeds_in):
+                         temperatures, top_ks, seeds_in, kind="prefix"):
         """Tail-only prefill for prefix hits AND mixed-step chunks: the
         already-resident pages are mapped into each slot's table (the core
         did map_prefix/cow_block/alloc_tail first), so ONE bucketed
@@ -543,6 +562,18 @@ class ModelRunner:
         tail width / prefix pages bucket to powers of two so the compile
         cache stays O(log^3) for a long-lived runner; dummy rows carry a
         zero prefix + length-1 tail and are never scattered."""
+        with tracing.span(self._spans[kind][0]):
+            args = self._stage_prefix(slots, tokens, prefix_lens,
+                                      temperatures, top_ks, seeds_in)
+        first, tail_caches, seconds = self._dispatch(
+            kind, self._prefix_prefill, *args)
+        self.stats.prefill_time += seconds
+        self.stats.prefill_tokens += sum(len(t) for t in tokens)
+        self.stats.prefill_dispatches += 1
+        return first, tail_caches
+
+    def _stage_prefix(self, slots, tokens, prefix_lens, temperatures,
+                      top_ks, seeds_in) -> tuple:
         ps = self.page_size
         group = len(slots)
         tail_lens = [len(t) for t in tokens]
@@ -569,19 +600,45 @@ class ModelRunner:
             seeds[j] = seeds_in[j]
 
         dpa = self._dpa()
-        t0 = time.perf_counter()
-        with self._trace_ctx():
-            first, tail_caches = self._prefix_prefill(
-                self.params, self.cache.data,
+        return (self.params, self.cache.data,
                 self._put(tables, P(dpa, None)),
                 self._put(tails, P(dpa, None)), self._put(plens, P(dpa)),
                 self._put(tlens, P(dpa)), self._put(temps, P(dpa)),
                 self._put(topk, P(dpa)), self._put(seeds, P(dpa)))
-        jax.block_until_ready((first, tail_caches))
-        self.stats.prefill_time += time.perf_counter() - t0
-        self.stats.prefill_tokens += int(sum(tail_lens))
-        self.stats.prefill_dispatches += 1
-        return np.asarray(first), tail_caches
+
+    def _dispatch(self, kind: str, fn, *args, **kwargs):
+        """Launch one compiled dispatch and wait for its results, under the
+        kind's launch and wait spans.  Returns (sampled tokens on the host,
+        K/V output, seconds of finished work: from the call to the tokens
+        in hand — the ``EngineStats`` dispatch timers)."""
+        _, launch_name, wait_name = self._spans[kind]
+        with tracing.span(launch_name) as launch, self._trace_ctx():
+            tokens, caches = fn(*args, **kwargs)
+        with tracing.span(wait_name) as wait:
+            jax.block_until_ready(caches)
+            tokens = np.asarray(tokens)
+        return tokens, caches, launch.seconds + wait.seconds
+
+    def _decode_staging(self, table=None) -> tuple:
+        """The decode step's per-call inputs but the weights and the pool:
+        the page table (``table``, else the full one; None unpaged) and the
+        staging rows."""
+        if table is None and self.page_size is not None:
+            table = self.cache.table_device()
+        return (table, jnp.asarray(self._tok), jnp.asarray(self._pos),
+                jnp.asarray(self._temps), jnp.asarray(self._topk),
+                jnp.asarray(self._seeds))
+
+    def decode_program_text(self) -> str | None:
+        """The compiled decode step's HLO text, op metadata (named scopes)
+        included, or None before its first dispatch.  The executable is
+        the one the dispatches ran (JAX's in-memory cache)."""
+        if not self._decode_ran:
+            return None
+        with self._trace_ctx():
+            lowered = self._step.lower(self.params, self.cache.data,
+                                       *self._decode_staging())
+        return lowered.compile().as_text()
 
     # ----------------------------------------------- cache execution ops --
     # The core decides WHEN to allocate/evict/swap (and how to reclaim on
@@ -592,35 +649,44 @@ class ModelRunner:
         callers pass ``lengths`` (real token counts) so only the mapped
         blocks are written; may raise PoolExhausted for the core to
         reclaim-and-retry WITHOUT re-dispatching."""
-        if lengths is None:
-            self.cache.insert(slots, caches)
-        else:
-            self.cache.insert(slots, caches, lengths=lengths)
+        with tracing.span(self._cache_spans["insert"]):
+            if lengths is None:
+                self.cache.insert(slots, caches)
+            else:
+                self.cache.insert(slots, caches, lengths=lengths)
 
     def write_tails(self, slots, tail_caches, *, starts, lengths, rows):
-        self.cache.write_tails(slots, tail_caches, starts=starts,
-                               lengths=lengths, rows=rows)
+        with tracing.span(self._cache_spans["write_tails"]):
+            self.cache.write_tails(slots, tail_caches, starts=starts,
+                                   lengths=lengths, rows=rows)
 
     def map_prefix(self, slot: int, blocks) -> None:
-        self.cache.map_prefix(slot, blocks)
+        with tracing.span(self._cache_spans["map_prefix"]):
+            self.cache.map_prefix(slot, blocks)
 
     def cow_block(self, slot: int, page_index: int, src_block: int) -> None:
-        self.cache.cow_block(slot, page_index, src_block)
+        with tracing.span(self._cache_spans["cow"]):
+            self.cache.cow_block(slot, page_index, src_block)
 
     def alloc_tail(self, slot: int, matched_len: int, prefill_len: int):
-        return self.cache.alloc_tail(slot, matched_len, prefill_len)
+        with tracing.span(self._cache_spans["alloc_tail"]):
+            return self.cache.alloc_tail(slot, matched_len, prefill_len)
 
     def ensure_mapped(self, slot: int, pos: int) -> None:
+        # no span per slot: the core spans a step's whole page-table growth
         self.cache.ensure_mapped(slot, pos)
 
     def evict(self, slots) -> None:
-        self.cache.evict(slots)
+        with tracing.span(self._cache_spans["evict"]):
+            self.cache.evict(slots)
 
     def swap_out(self, slot: int):
-        return self.cache.swap_out(slot)
+        with tracing.span(self._cache_spans["swap_out"]):
+            return self.cache.swap_out(slot)
 
     def swap_in(self, slot: int, state) -> None:
-        self.cache.swap_in(slot, state)
+        with tracing.span(self._cache_spans["swap_in"]):
+            self.cache.swap_in(slot, state)
 
     # ---------------------------------------------------------- staging ---
     def set_slot(self, slot: int, *, token: int, pos: int,

@@ -59,7 +59,8 @@ class EngineStats:
     """Cumulative throughput counters (wall clock, block_until_ready'd).
 
     The ModelRunner owns the device-side fields (prefill_*/decode_* —
-    accumulated around its compiled dispatches), the EngineCore owns the
+    each dispatch's launch and wait spans: from the jitted call to its
+    sampled tokens on the host, i.e. finished work), the EngineCore owns the
     policy fields (preemptions/recomputed/swap counters) and ``host_time``:
     each ``step()`` adds its wall time MINUS whatever the runner spent
     inside dispatches, so scheduling/bookkeeping overhead is visible
@@ -79,6 +80,11 @@ class EngineStats:
     # prefill inflicts on running slots, and the number chunked prefill
     # exists to bound (before/after evidence for --chunk-size)
     max_decode_stall: float = 0.0
+    # non-chunked admission steps (prefill / prefix waves) that ran while
+    # at least one admitted sequence was decode-ready, and their summed
+    # wall time: what each wave costs the running slots
+    prefill_stall_time: float = 0.0
+    prefill_stall_waves: int = 0
     # host-vs-device split: step() wall time not spent inside a compiled
     # dispatch (scheduling, cache bookkeeping, event emission)
     host_time: float = 0.0
