@@ -12,11 +12,16 @@ One chip:
   (b) kernels: the fused butterfly (N = 4096) and pixelfly (N = 16384)
       Pallas kernels, b = 128, compiled natively at decode (M = 4) and
       prefill (M = 512) rows, against their jnp references.
-  (c) serve: qwen3-4b at its published widths, dense and under its
+  (c) paged attention: the paged decode attention kernel and the gather
+      path it replaces at the chat cell's decode pool (32 rows, 96 pages
+      of 16, 1485 blocks, 32 q heads and 8 kv heads of 128; ragged rows,
+      some idle), compiled natively: their largest difference and each
+      one's time per layer.
+  (d) serve: qwen3-4b at its published widths, dense and under its
       recommended butterfly/pixelfly policy, through the serve CLI's own
       setup; 4 requests of 64 prompt tokens and 32 new tokens each over the
       paged cache, checked against the token-by-token reference.
-  (d) train: three steps of butterfly-lm-100m at full width through the
+  (e) train: three steps of butterfly-lm-100m at full width through the
       training launcher's loop, with no failed step tolerated.
 
 --four-chips runs only: minitron-8b (about 19.8 GB of bf16 weights, more
@@ -167,8 +172,68 @@ def run_kernels() -> None:
                   f"warm call {warm * 1e3:.3f} ms", flush=True)
 
 
+def _per_call(fn, *args, calls: int = 20) -> float:
+    """Seconds per call of a warm jitted ``fn``, over ``calls`` calls."""
+    jax.block_until_ready(fn(*args))
+    t0 = time.perf_counter()
+    for _ in range(calls):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t0) / calls
+
+
+def run_paged_attention() -> None:
+    """(c) The paged decode attention kernel against the gather path at
+    the chat cell's pool: 21 live rows of ragged lengths (1 and 1536
+    among them) and 11 idle ones, one layer's K and V.  The two differ by
+    the bf16 rounding of the softmax weights into the MXU and of their
+    outputs, well inside KERNEL_TOL; a wrong page or mask is O(1)."""
+    from repro.kernels.paged_attention.ops import paged_attention
+    from repro.kernels.paged_attention.ref import paged_decode_attention_ref
+
+    rows, live_rows, max_pages, page, blocks = 32, 21, 96, 16, 1485
+    hq, hkv, hd = 32, 8, 128
+    rng = np.random.default_rng(0)
+    lengths = np.zeros(rows, np.int32)
+    live = np.sort(rng.choice(rows, live_rows, replace=False))
+    lengths[live] = rng.integers(1, max_pages * page + 1, live_rows)
+    lengths[live[:2]] = (1, max_pages * page)
+    table = np.zeros((rows, max_pages), np.int32)
+    ids = rng.permutation(np.arange(1, blocks))
+    used = 0
+    for r in live:
+        n = -(-int(lengths[r]) // page)
+        table[r, :n] = ids[used:used + n]
+        used += n
+    kk, kv, kq = jax.random.split(jax.random.PRNGKey(0), 3)
+    shape = (blocks, page, hkv, hd)
+    k = jax.random.normal(kk, shape, jnp.bfloat16)
+    v = jax.random.normal(kv, shape, jnp.bfloat16)
+    q = (jax.random.normal(kq, (rows, hq, hd)) * hd ** -0.5).astype(
+        jnp.bfloat16)
+    lens, tab = jnp.asarray(lengths), jnp.asarray(table)
+
+    want = np.asarray(paged_decode_attention_ref(q, k, v, lens, tab),
+                      np.float32)
+    t_gather = _per_call(paged_decode_attention_ref, q, k, v, lens, tab)
+    pages = int(sum(-(-int(n) // page) for n in lengths))
+    print(f"  gather path: {t_gather * 1e3:.4f} ms per layer "
+          f"({rows} x {max_pages} pages gathered)", flush=True)
+    kernel = jax.jit(paged_attention)
+    got = np.asarray(kernel(q, k, v, lens, tab), np.float32)
+    t = _per_call(kernel, q, k, v, lens, tab)
+    # K/V bytes of the pages walked, over the kernel's time
+    rate = pages * 2 * page * hkv * hd * 2 / t / 1e9
+    print(f"  kernel: {t * 1e3:.4f} ms per layer ({pages} pages walked, "
+          f"{rate:.1f} GB/s of K/V); max |diff| against the gather path "
+          f"{np.abs(got - want).max():.3e} (|ref| max "
+          f"{np.abs(want).max():.3f})", flush=True)
+    np.testing.assert_allclose(got, want, **KERNEL_TOL,
+                               err_msg="paged attention")
+
+
 def serve_and_check(argv: list[str], label: str):
-    """(c) Build the engine exactly as the serve CLI does, serve
+    """(d) Build the engine exactly as the serve CLI does, serve
     NUM_REQUESTS greedy requests and check them against the reference.
     Returns the engine, which holds the served weights."""
     from repro.launch.serve import build_parser, setup
@@ -252,7 +317,7 @@ def compare_to_reference(label, outputs, last, ref_tok, ref_logits) -> None:
 
 
 def run_train() -> None:
-    """(d) Three full-width butterfly-lm-100m steps, no failure tolerated."""
+    """(e) Three full-width butterfly-lm-100m steps, no failure tolerated."""
     from repro.configs import get_config
     from repro.launch.train import train
 
@@ -307,6 +372,8 @@ def main() -> int:
         from repro.configs.qwen3_4b import FACT_POLICY
         with phase("kernels"):
             run_kernels()
+        with phase("paged attention"):
+            run_paged_attention()
         with phase("serve qwen3-4b dense"):
             serve_qwen(["--arch", "qwen3-4b"], "qwen3-4b")
         with tempfile.TemporaryDirectory() as tmp:

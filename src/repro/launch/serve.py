@@ -334,6 +334,10 @@ def stats_payload(engine: Engine, state: ServerState) -> dict:
             "decode_tokens": st.decode_tokens,
             "decode_steps": st.decode_steps,
             "decode_tps": st.decode_tps,
+            # decode steps whose attention ran the paged kernel, and the
+            # K/V pages it read over them
+            "attn_kernel_steps": st.attn_kernel_steps,
+            "kv_pages_walked": st.kv_pages_walked,
             # chunked-prefill composition (--chunk-size): chunk groups run
             # beside decode rows; max_decode_stall_s is the longest gap
             # between decode dispatches while a slot sat decode-ready —

@@ -4,8 +4,9 @@ flash-style training attention, and KV-cache decode.
 Compiled programs carry named scopes for the parts of attention a device
 trace should tell apart: ``attn.rope``, ``attn.kv_write`` (new K/V into the
 cache or pool), ``attn.kv_gather`` (a paged view gathered from the pool)
-and ``attn.core`` (scores, mask, softmax, values).  The projections carry
-their own (``Linear``: ``fact.<kind>.<site>`` / ``dense.<site>``)."""
+and ``attn.core`` (scores, mask, softmax, values; on the chip's paged
+decode path, the paged attention kernel).  The projections carry their
+own (``Linear``: ``fact.<kind>.<site>`` / ``dense.<site>``)."""
 from __future__ import annotations
 
 from typing import Any
@@ -17,6 +18,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.core.factorized import Linear
+from repro.kernels.paged_attention import ops as paged_ops
 from repro.models.layers import apply_mrope, apply_rope, init_rms_norm, rms_norm
 from repro.parallel import context as pctx
 
@@ -210,9 +212,11 @@ def _decode_attention(params: dict, cfg: ModelConfig, q: jax.Array,
                       out_dtype) -> jax.Array:
     """Shared single-token attention core: q (B, 1, Hq, hd) against a dense
     K/V view (B, T, Hkv, hd) with causal validity ``t <= pos``, followed by
-    the output projection.  Both the fixed-stripe and paged decode paths
-    end here — bit-exact parity between them depends on this being the ONE
-    place the decode attention math lives."""
+    the output projection.  The fixed-stripe path and the paged gather path
+    end here, so those two are bit-identical; the paged kernel
+    (:func:`attn_decode_paged` on the chip) computes the same attention in
+    another summation order, so on the chip paged and fixed-stripe decode
+    agree to rounding, not to the bit."""
     b = q.shape[0]
     t = k.shape[1]
     hq, hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.hd
@@ -257,6 +261,18 @@ def attn_decode(
     return y, {"k": k, "v": v}
 
 
+def paged_kernel_engages(cfg: ModelConfig, pool_dtype) -> bool:
+    """Whether :func:`attn_decode_paged` takes the paged attention kernel:
+    on a TPU, with no mesh of more than one device installed (there the
+    pool's kv heads or rows are sharded, and the kernel reads a whole
+    pool), for a pool the kernel reads in place
+    (:func:`repro.kernels.paged_attention.ops.supports`).  Everywhere else
+    the gather path runs."""
+    mesh = pctx.current_mesh()
+    return (paged_ops.on_tpu() and (mesh is None or mesh.size == 1)
+            and paged_ops.supports(cfg.hd, cfg.num_kv_heads, pool_dtype))
+
+
 def attn_decode_paged(
     params: dict, cfg: ModelConfig, x: jax.Array, cache: dict,
     page_table: jax.Array, pos: jax.Array, page_size: int, kv_len: int
@@ -270,13 +286,22 @@ def attn_decode_paged(
 
     The write scatters one (page_size-row) entry: block
     ``page_table[b, pos // page_size]``, row ``pos % page_size``.  Idle
-    decode rows (pos 0, all-zero table row) write the scratch block, which
-    no mapped gather ever reads.  The gather pulls each row's pages into a
-    dense view sliced to exactly ``kv_len`` positions, so the attention
+    decode rows (pos 0, all-zero table row) write the scratch block.
+
+    Where :func:`paged_kernel_engages` (on the chip), the paged attention
+    kernel then reads each row's pages ``0 .. pos // page_size`` from the
+    pool itself and nothing past them; a row whose first table entry is
+    the scratch block (idle, or masked out of a mixed step) reads none,
+    and its output is discarded.  It differs from the gather path only in
+    summation order.
+
+    Elsewhere (the CPU, a mesh) the gather path pulls each row's pages into
+    a dense view sliced to exactly ``kv_len`` positions, so the attention
     math downstream is shape- and bit-identical to :func:`attn_decode` on
     a fixed (B, kv_len) cache: positions beyond ``pos`` may hold stale page
     contents, but the causal validity mask sends them to NEG_INF exactly
-    as the fixed path does for its zero-initialized rows.
+    as the fixed path does for its zero-initialized rows.  It is the
+    kernel's parity oracle.
     """
     b = x.shape[0]
     positions = pos[:, None]  # (B, 1)
@@ -293,7 +318,16 @@ def attn_decode_paged(
         v_pool = cache["v"].at[bid, off].set(
             v_new[:, 0].astype(cache["v"].dtype))
 
-    hkv, hd = cfg.num_kv_heads, cfg.hd
+    hq, hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.hd
+    if paged_kernel_engages(cfg, k_pool.dtype):
+        with jax.named_scope("attn.core"):
+            lengths = jnp.where(page_table[:, 0] > 0, pos + 1, 0)
+            o = paged_ops.paged_attention((q * hd ** -0.5)[:, 0], k_pool,
+                                          v_pool, lengths, page_table)
+            o = o.reshape(b, 1, hq * hd).astype(x.dtype)
+        _, out_lin = _linears(cfg)
+        return out_lin(params["out"], o), {"k": k_pool, "v": v_pool}
+
     with jax.named_scope("attn.kv_gather"):
         k = k_pool[page_table].reshape(b, -1, hkv, hd)[:, :kv_len]
         v = v_pool[page_table].reshape(b, -1, hkv, hd)[:, :kv_len]

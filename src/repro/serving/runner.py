@@ -38,6 +38,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs.base import ModelConfig
 from repro.models import (decode_step, init_params, prefill,
                           prefill_with_past, prefill_with_prefix)
+from repro.models.attention import paged_kernel_engages
 from repro.models.model import cast_params
 import repro.serving.tracing as tracing
 from repro.parallel import context as pctx
@@ -237,6 +238,14 @@ class ModelRunner:
             "insert", "write_tails", "map_prefix", "cow", "alloc_tail",
             "evict", "swap_out", "swap_in")}
         self._decode_ran = False
+        # whether the decode step's attention takes the paged kernel
+        # (decided from the platform, the mesh and the shapes, as the
+        # traced step decides it)
+        with self._trace_ctx():
+            self._paged_kernel = (
+                page_size is not None
+                and any(m == "attn" for m, _ in cfg.pattern)
+                and paged_kernel_engages(cfg, cfg.dtype))
         if not role:
             tracing.publish("jit_step_fn", self.decode_program_text)
         self._sample = _make_sampler(cfg, self.max_top_k)
@@ -415,16 +424,24 @@ class ModelRunner:
         table is the same shape/dtype as the full one — a value change,
         never a recompile."""
         with tracing.span(self._spans[kind][0]):
-            if self.page_size is None or live_rows is None:
+            table = None if self.page_size is None else self.cache.table
+            if table is None or live_rows is None:
                 staged = self._decode_staging()
             else:
-                masked = np.zeros_like(self.cache.table)
+                table = np.zeros_like(self.cache.table)
                 rows = list(live_rows)
                 if rows:
-                    masked[rows] = self.cache.table[rows]
-                staged = self._decode_staging(jnp.asarray(masked))
+                    table[rows] = self.cache.table[rows]
+                staged = self._decode_staging(jnp.asarray(table))
+            meta = {}
+            if self._paged_kernel:
+                meta = {"attn_kernel_steps": 1,
+                        "kv_pages_walked": self._pages_walked(table)}
         nxt, self.cache.data, seconds = self._dispatch(
-            kind, self._step, self.params, self.cache.data, *staged)
+            kind, self._step, self.params, self.cache.data, *staged,
+            meta=meta)
+        self.stats.attn_kernel_steps += meta.get("attn_kernel_steps", 0)
+        self.stats.kv_pages_walked += meta.get("kv_pages_walked", 0)
         self._decode_ran = True
         self.stats.decode_time += seconds
         self.stats.decode_steps += 1
@@ -606,13 +623,23 @@ class ModelRunner:
                 self._put(tlens, P(dpa)), self._put(temps, P(dpa)),
                 self._put(topk, P(dpa)), self._put(seeds, P(dpa)))
 
-    def _dispatch(self, kind: str, fn, *args, **kwargs):
+    def _pages_walked(self, table: np.ndarray) -> int:
+        """Pages the paged attention kernel reads in one decode step over
+        ``table``: ``ceil((pos + 1) / page_size)`` for each row whose first
+        entry is a mapped block (the kernel reads nothing of the rest)."""
+        live = table[:, 0] != 0
+        return int(((self._pos[live] + self.page_size)
+                    // self.page_size).sum())
+
+    def _dispatch(self, kind: str, fn, *args, meta=None, **kwargs):
         """Launch one compiled dispatch and wait for its results, under the
-        kind's launch and wait spans.  Returns (sampled tokens on the host,
-        K/V output, seconds of finished work: from the call to the tokens
-        in hand — the ``EngineStats`` dispatch timers)."""
+        kind's launch and wait spans (``meta``: ints for the launch span).
+        Returns (sampled tokens on the host, K/V output, seconds of
+        finished work: from the call to the tokens in hand — the
+        ``EngineStats`` dispatch timers)."""
         _, launch_name, wait_name = self._spans[kind]
-        with tracing.span(launch_name) as launch, self._trace_ctx():
+        with tracing.span(launch_name, **(meta or {})) as launch, \
+                self._trace_ctx():
             tokens, caches = fn(*args, **kwargs)
         with tracing.span(wait_name) as wait:
             jax.block_until_ready(caches)

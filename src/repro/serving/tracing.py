@@ -23,7 +23,11 @@ The vocabulary (DESIGN.md section 14):
 ``serve.reclaim``               ``EngineCore._reclaim``
 ``serve.stage.<kind>``          host arrays, page table and uploads of one
                                 runner dispatch
-``serve.launch.<kind>``         the jitted call until it returns
+``serve.launch.<kind>``         the jitted call until it returns; a
+                                decode (or mixed) launch whose attention
+                                takes the paged kernel has meta
+                                ``attn_kernel_steps`` (1) and
+                                ``kv_pages_walked`` (pages it reads)
 ``serve.wait.<kind>``           waiting for its results on the host
 ``serve.cache.<op>``            insert, write_tails, evict, ensure_mapped
                                 (a step's whole page-table growth),

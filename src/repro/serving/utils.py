@@ -75,6 +75,11 @@ class EngineStats:
     decode_tokens: int = 0
     decode_time: float = 0.0
     decode_steps: int = 0
+    # decode dispatches whose attention took the paged kernel, and the
+    # pages it read over them (ceil((pos + 1) / page_size) per live row);
+    # over decode_steps x slots x max_pages: the share of a dense gather
+    attn_kernel_steps: int = 0
+    kv_pages_walked: int = 0
     # longest wall-clock gap between consecutive decode dispatches while at
     # least one admitted sequence was decode-ready — the stall a monolithic
     # prefill inflicts on running slots, and the number chunked prefill
