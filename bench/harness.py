@@ -25,7 +25,7 @@ from pathlib import Path
 import numpy as np
 
 from bench import trace_reduce
-from bench.model import load_config, model_config
+from bench.model import arch, load_config, model_config, reference
 from bench.peaks import peaks_for
 from bench.traffic_gen import Plan, load_traffic, make_plan
 
@@ -228,7 +228,7 @@ def build(cell: Cell, seed: int, memory_bytes: int | None = None):
     if mesh is not None:
         from repro.parallel.sharding import partition_params, to_named
         shardings = to_named(mesh, partition_params(cfg, mesh))
-    params = make_like(seed, shapes, shardings)
+    params = make_like(seed, shapes, shardings, arch(c))
     if memory_bytes is None:
         memory_bytes = min(d.memory_stats()["bytes_limit"]
                            for d in jax.devices()[:dp * tp])
@@ -567,9 +567,7 @@ def compare(cell: Cell, seed: int, sample: list, max_len: int,
     the standard deviation of that position's reference logits.  With
     ``control`` also ``control_gap``: the same for the token the float8
     control puts first at each of those positions."""
-    import importlib
-    ref_mod = importlib.import_module(f"bench.refs.{cell.config['reference']}")
-    ref = ref_mod.Reference(cell.config, seed)
+    ref = reference(cell.config).Reference(cell.config, seed)
     n = len(sample)
     block = 4
     rows = -(-max(n, 1) // block) * block

@@ -1,8 +1,9 @@
 """Share of the HBM roofline the decode step reaches, in %: the fewest
-bytes a step must read on one chip (weights under the cell's policy plus
-the K/V of its live tokens, bench/counts.py) at the chip's peak bandwidth,
-over the decode program's device time, averaged over the traced steps
-(model step)."""
+bytes a step must read on one chip (weights under the cell's policy, of
+expert leaves only the experts its steps read, plus the K/V of its live
+tokens, bench/counts.py) at the chip's peak bandwidth, over the decode
+program's device time, averaged over the traced steps (model step).
+Nothing where the model has experts and the run no counter of those read."""
 from bench import counts, trace_reduce
 
 MODULE = "jit_step_fn"
@@ -10,6 +11,9 @@ MODULE = "jit_step_fn"
 
 def read(run):
     if run.trace is None or run.peaks is None:
+        return None
+    experts = counts.experts_read_per_step(run.config, run.stats)
+    if experts is None:
         return None
     ev = trace_reduce.module_events(run.trace["events"], run.trace["plane"],
                                     MODULE)
@@ -19,7 +23,7 @@ def read(run):
     if not ev or not steps:
         return None
     need = sum(counts.decode_min_bytes_per_chip(
-        run.config, run.tp, sum(s.decode_contexts)) for s in steps)
+        run.config, run.tp, sum(s.decode_contexts), experts) for s in steps)
     t_min = need / len(steps) / run.peaks.hbm_bw
     t_dev = sum(e.dur_ns for e in ev) / len(ev) / 1e9
     return 100.0 * t_min / t_dev

@@ -1,12 +1,26 @@
 """A configuration file (``bench/configs/<name>.json``) as the program's
 ``ModelConfig``: the published sizes are read here and nowhere else, so a
-later edit of the program's own configs cannot move the yardstick."""
+later edit of the program's own configs cannot move the yardstick.
+
+What belongs to one architecture lives in files of its own, found by name
+under the benchmark's data directory (the parent of the configuration's
+directory, recorded in the dict as ``bench_dir``):
+
+- ``archs/<model_type>.py``: ``program_config(c)`` builds the program's
+  ``ModelConfig``; optionally ``scale_rule(path, shape)`` and ``STACKED``
+  for the seeded weights (:mod:`bench.weights`);
+- ``refs/<reference>.py``: the plain reference and its layout, which
+  :mod:`bench.counts` counts from.
+"""
 from __future__ import annotations
 
+import functools
+import importlib.util
 import json
 from pathlib import Path
 
-CONFIG_DIR = Path(__file__).resolve().parent / "configs"
+BENCH = Path(__file__).resolve().parent
+CONFIG_DIR = BENCH / "configs"
 
 
 def load_config(name: str, directory: Path = CONFIG_DIR) -> dict:
@@ -15,30 +29,38 @@ def load_config(name: str, directory: Path = CONFIG_DIR) -> dict:
         raise FileNotFoundError(f"no configuration file {path}")
     cfg = json.loads(path.read_text())
     cfg["name"] = name
+    cfg["bench_dir"] = str(Path(directory).resolve().parent)
     return cfg
 
 
-def model_config(c: dict):
-    """The program's ModelConfig for configuration dict ``c``, served in
-    bf16 (parameters held in bf16 too, so the program prices them as
-    served)."""
-    import jax.numpy as jnp
-    from repro.configs.base import ModelConfig
-    from repro.core.policy import FactorizationPolicy
+@functools.lru_cache(maxsize=None)
+def _load(path: str):
+    spec = importlib.util.spec_from_file_location(
+        f"bench_{Path(path).parent.name}_{Path(path).stem}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
-    if c["hidden_act"] != "silu" or c.get("attention_bias"):
-        raise ValueError(f"{c['name']}: only SwiGLU without attention bias "
-                         "is supported")
-    return ModelConfig(
-        name=c["name"], family="dense",
-        num_layers=c["num_hidden_layers"], d_model=c["hidden_size"],
-        num_heads=c["num_attention_heads"],
-        num_kv_heads=c["num_key_value_heads"], d_ff=c["intermediate_size"],
-        vocab_size=c["vocab_size"], head_dim=c["head_dim"],
-        qk_norm=c["model_type"] == "qwen3", rope_theta=float(c["rope_theta"]),
-        norm_eps=float(c["rms_norm_eps"]),
-        # the program holds its own output head whether or not the
-        # published model ties it to the embedding (``assumed`` in the file)
-        tie_embeddings=False,
-        fact=FactorizationPolicy.from_dict(c["policy"]),
-        dtype=jnp.bfloat16, param_dtype=jnp.bfloat16, remat=False)
+
+def load_module(c: dict, kind: str, name: str):
+    """``<bench_dir>/<kind>/<name>.py`` of configuration ``c``, loaded by
+    path once per process."""
+    path = Path(c.get("bench_dir", BENCH)) / kind / f"{name}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"{c.get('name')}: no {kind} module {path}")
+    return _load(str(path))
+
+
+def arch(c: dict):
+    """The architecture module of ``c``, by its ``model_type``."""
+    return load_module(c, "archs", c["model_type"])
+
+
+def reference(c: dict):
+    """The reference module of ``c``, by its ``reference``."""
+    return load_module(c, "refs", c["reference"])
+
+
+def model_config(c: dict):
+    """The program's ModelConfig for configuration dict ``c``."""
+    return arch(c).program_config(c)

@@ -115,6 +115,18 @@ def top_layout(c: dict) -> dict:
     return out
 
 
+def kv_bytes_per_token(c: dict) -> int:
+    """K and V of one token, all layers, bf16."""
+    return (2 * 2 * c["num_hidden_layers"] * c["num_key_value_heads"]
+            * c["head_dim"])
+
+
+def attention_flops(c: dict, context: int) -> float:
+    """Scores and weighted values of one token over ``context`` positions."""
+    return (4.0 * c["num_hidden_layers"] * c["num_attention_heads"]
+            * c["head_dim"] * context)
+
+
 # ------------------------------------------------------------ numerics ---
 def _q(x, precision: str):
     """Round to float8 e4m3 with one scale per tensor (the control), or

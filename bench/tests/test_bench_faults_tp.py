@@ -1,6 +1,7 @@
 """The tensor-parallel cell's run at a tiny size on four simulated
-devices: sound, it comes out correct; with the exchange between chips
-left out, it does not."""
+devices: sound, it comes out correct; its float8 control, and each fault
+planted under the timed path (the exchange between chips left out among
+them), does not."""
 import json
 import os
 import subprocess
@@ -18,10 +19,12 @@ from bench.harness import run
 dense = {{"default": {{"kind": "dense"}}, "overrides": {{}}}}
 cell = tiny_cell("closed", dense, tp=4)
 out = {{}}
-for name, fault in (("sound", None), ("exchange_dropped",
-                                      faults.exchange_dropped)):
+runs = [("sound", None, False), ("control", None, True)] + [
+    (name, fault, False) for name, fault in
+    sorted({{**faults.FAULTS, **faults.FAULTS_TP}}.items())]
+for name, fault, control in runs:
     r = run(cell, 77, 1.5, False, require_tpu=False,
-            memory_bytes=50_000_000, fault=fault)
+            memory_bytes=50_000_000, fault=fault, control=control)
     out[name] = [r["correct"], r["checks"], r["metrics"]]
 print(json.dumps(out))
 """
@@ -37,4 +40,7 @@ def test_tp_run_and_dropped_exchange():
     out = json.loads(p.stdout.strip().splitlines()[-1])
     assert out["sound"][0], out["sound"]
     assert "output_tokens_per_s" in out["sound"][2]
-    assert not out["exchange_dropped"][0], out["exchange_dropped"]
+    assert set(out) == {"sound", "control", "exchange_dropped",
+                        "state_unchanged", "token_altered"}
+    for name in set(out) - {"sound"}:
+        assert not out[name][0], (name, out[name])

@@ -2,9 +2,11 @@
 reference.
 
 Every leaf is addressed by its path in the parameter tree (``embed``,
-``periods/slot0/mixer/qkv/factors/0``, ...) and, under ``periods``, by its
-layer.  Its values are a pure function of (seed, path, layer): uniform
-draws of unit variance, scaled by a rule on the path.  The program's
+``periods/slot0/mixer/qkv/factors/0``, ...) and, under ``periods`` and the
+architecture's other stacked prefixes (``STACKED`` of its
+``archs/<model_type>.py``), by its layer.  Its values are a pure function
+of (seed, path, layer): uniform draws of unit variance, scaled by a rule
+on the path, the architecture's own ``scale_rule`` first.  The program's
 weights are made from these in one jitted call, directly in bf16 and under
 the program's own shardings; the reference regenerates each layer from the
 same function when it needs it, so it takes nothing the program made.
@@ -33,9 +35,16 @@ def path_str(path) -> str:
     return "/".join(str(getattr(p, "key", getattr(p, "idx", p))) for p in path)
 
 
-def scale_rule(path: str, shape: tuple[int, ...]) -> tuple[float, float]:
+def scale_rule(path: str, shape: tuple[int, ...],
+               rule=None) -> tuple[float, float]:
     """(offset, scale) of a leaf whose per-layer shape is ``shape``: norm
-    gains near 1, every matrix scaled so a product keeps unit variance."""
+    gains near 1, every matrix scaled so a product keeps unit variance.
+    ``rule(path, shape)``, an architecture's own, is asked first; where it
+    returns None the shared rules below apply."""
+    if rule is not None:
+        own = rule(path, shape)
+        if own is not None:
+            return own
     parts = path.split("/")
     last = parts[-1]
     if last in NORMS:
@@ -51,9 +60,11 @@ def scale_rule(path: str, shape: tuple[int, ...]) -> tuple[float, float]:
     raise ValueError(f"no scale rule for leaf {path!r}")
 
 
-def leaf_value(key: jax.Array, path: str, shape, layer) -> jax.Array:
-    """One layer's slice of leaf ``path`` (bf16); ``layer`` may be traced."""
-    offset, scale = scale_rule(path, tuple(shape))
+def leaf_value(key: jax.Array, path: str, shape, layer,
+               rule=None) -> jax.Array:
+    """One layer's slice of leaf ``path`` (bf16); ``layer`` may be traced.
+    ``rule`` is the architecture's ``scale_rule``, if it has one."""
+    offset, scale = scale_rule(path, tuple(shape), rule)
     k = jax.random.fold_in(jax.random.fold_in(
         key, zlib.crc32(path.encode()) & 0x7FFFFFFF), layer)
     bits = jax.random.bits(k, tuple(shape), jnp.uint32)
@@ -61,22 +72,28 @@ def leaf_value(key: jax.Array, path: str, shape, layer) -> jax.Array:
     return (offset + u * (scale * math.sqrt(12.0))).astype(jnp.bfloat16)
 
 
-def make_like(seed: int, shapes, shardings=None):
+def make_like(seed: int, shapes, shardings=None, arch=None):
     """A tree shaped like ``shapes`` (ShapeDtypeStructs, as ``eval_shape``
     gives the program's parameters) filled from the seed, made on the
-    device in one jitted call, in bf16, placed per ``shardings``."""
+    device in one jitted call, in bf16, placed per ``shardings``.  Leaves
+    under ``periods/`` and under the prefixes of ``arch.STACKED`` (the
+    architecture module) are filled one layer of their leading axis at a
+    time; ``arch.scale_rule`` is asked before the shared rules."""
+    rule = getattr(arch, "scale_rule", None)
+    stacked = tuple(p.rstrip("/") + "/" for p in
+                    ("periods",) + tuple(getattr(arch, "STACKED", ())))
     flat, treedef = jax.tree_util.tree_flatten_with_path(shapes)
 
     def build(key):
         out = []
         for p, leaf in flat:
             path = path_str(p)
-            if path.startswith("periods/"):
+            if path.startswith(stacked):
                 per = leaf.shape[1:]
                 out.append(jax.vmap(lambda l, path=path, per=per: leaf_value(
-                    key, path, per, l))(jnp.arange(leaf.shape[0])))
+                    key, path, per, l, rule))(jnp.arange(leaf.shape[0])))
             else:
-                out.append(leaf_value(key, path, leaf.shape, 0))
+                out.append(leaf_value(key, path, leaf.shape, 0, rule))
         return jax.tree_util.tree_unflatten(treedef, out)
 
     return jax.jit(build, out_shardings=shardings)(seed_key(seed))
